@@ -12,9 +12,9 @@ tracks the satellites around it:
   acceptance geometry (capacity 2^17, B = 64 write-back lanes) the
   incremental write must be >= 3x faster than the rebuild-based write.
 * ``ingest_fused`` rows — THE SECOND GATE (``--check``): one dispatch for
-  the whole add (priority init + storage scatter + tree repair, the fused
-  Pallas ingest op on TPU / one fused XLA graph elsewhere) must be >=
-  1.3x the three-dispatch alloc→store→``sumtree.write`` chain it replaced.
+  the whole add (priority init + storage scatter + tree repair in one
+  jitted ``add_fifo``, whose tree write runs the update kernel on TPU) must
+  be >= 1.3x the three-dispatch alloc→store→``sumtree.write`` chain.
 * ``sample_fused`` rows — ``sample_with_mass`` is backend-dispatched per
   path: on XLA it *is* the descent + leaf gather (bitwise, and within
   noise of it — the earlier committed 0.69x row was the fused lowering
@@ -236,18 +236,17 @@ def main() -> int:
     row(f"add_donated_cap{add_cap}_obs{obs_dim}", us_don,
         f"{us_cp / max(us_don, 1e-9):.2f}x")
 
-    # -- fused ingest: one dispatch vs the alloc→store→write chain --------
+    # -- one-dispatch ingest vs the alloc→store→write chain ---------------
     # The second gate. Reference is the replaced chain *as it ran*: three
     # separate device dispatches — (1) index/mask/leaf prep, (2) storage
     # scatter, (3) tree write — composed eagerly like every other
     # reference row here (no cross-call donation: a chain of independent
     # jits cannot update the storage pytree in place, so each scatter
-    # copies the buffers it touches). The fused side is the live code:
-    # ``add_fifo`` routed through ``_ingest`` — the single Pallas ingest
-    # kernel on TPU (one VMEM round-trip), one fused XLA graph with the
-    # state donated elsewhere. One dispatch + in-place storage is
-    # precisely the fused op's claim; the donation-only share of the win
-    # is tracked separately by the ``add_donated`` row above.
+    # copies the buffers it touches). The other side is the live code:
+    # ``add_fifo`` jitted as one program with the state donated (its tree
+    # write is the update kernel on TPU). One dispatch + in-place storage
+    # is the claim; the donation-only share of the win is tracked
+    # separately by the ``add_donated`` row above.
     rcfg_add = wcfg.replay
     offs = jnp.arange(add_lanes, dtype=jnp.int32)
 
@@ -327,7 +326,7 @@ def main() -> int:
                   f"{args.min_speedup:.1f}x)", file=sys.stderr)
             failed = True
         if ingest_speedup < args.min_ingest_speedup:
-            print(f"FAIL: fused ingest only {ingest_speedup:.2f}x the "
+            print(f"FAIL: one-dispatch ingest only {ingest_speedup:.2f}x the "
                   f"three-dispatch chain at cap={add_cap} "
                   f"lanes={add_lanes} (need >= "
                   f"{args.min_ingest_speedup:.1f}x)", file=sys.stderr)

@@ -255,11 +255,7 @@ def make_train_fn(cfg: ApexConfig, env, agent, optimizer, mesh=None,
             lambda st: train_iteration(cfg, env, agent, optimizer, st, 0, None))
         return init_fn, step_fn
 
-    if hasattr(jax, "shard_map"):
-        shard_map = functools.partial(jax.shard_map, check_vma=False)
-    else:  # jax < 0.5: the API lived in jax.experimental with check_rep
-        from jax.experimental.shard_map import shard_map as _shard_map
-        shard_map = functools.partial(_shard_map, check_rep=False)
+    shard_map = functools.partial(jax.shard_map, check_vma=False)
 
     def per_shard_init(rng):
         sid = jax.lax.axis_index(data_axis)

@@ -18,11 +18,10 @@ Eviction strategies (both from the paper):
 
 Slots are the paper's "keys": a transition's global key is (shard, slot).
 
-Both add modes funnel into one ingest contract — packed items, slot indices,
-an ``applied`` lane mask — dispatched like the sum-tree hot ops: a fused
-Pallas kernel (``repro.kernels.replay_ingest``) does priority init, storage
-scatter, and tree repair in one VMEM round-trip on TPU, with the unfused
-XLA chain (:func:`ingest_unfused`) as the bit-identical fallback/oracle.
+Both add modes funnel into one ingest contract (:func:`ingest`) — packed
+items, slot indices, an ``applied`` lane mask: priority init and the storage
+scatter in XLA, the tree repair through ``sumtree.write`` (the Pallas update
+kernel on TPU).
 """
 
 from __future__ import annotations
@@ -94,18 +93,20 @@ def _store(storage: Any, idx: jax.Array, items: Any) -> Any:
     return jax.tree.map(lambda buf, x: buf.at[idx].set(x.astype(buf.dtype)), storage, items)
 
 
-def ingest_unfused(
+def ingest(
     cfg: ReplayConfig, state: ReplayState, items: Any, priorities: jax.Array,
     idx: jax.Array, applied: jax.Array,
 ) -> tuple[Any, jax.Array]:
-    """The pre-fusion ingest chain (XLA fallback and the fused op's oracle).
+    """One ingest: leaf init, per-buffer storage scatter, tree write.
 
-    Three logical dispatches — leaf init, per-buffer storage scatter,
-    incremental tree write — with gather-then-scatter semantics throughout:
-    masked (``~applied``) lanes re-write their slot's *original* leaf and
-    row, so they are no-ops except under duplicate slots, where the scatter's
-    last-writer-wins applies. Out-of-range lanes (``add_alloc``'s overflow
-    fill value ``capacity``) drop on every scatter.
+    Both add modes reduce to this contract once their slot indices and lane
+    mask are computed (FIFO cursor arithmetic / ``free_slot_idx``).
+    Gather-then-scatter semantics throughout: masked (``~applied``) lanes
+    re-write their slot's *original* leaf and row, so they are no-ops except
+    under duplicate slots, where the scatter's last-writer-wins applies.
+    Out-of-range lanes (``add_alloc``'s overflow fill value ``capacity``)
+    drop on every scatter. The tree write follows the sum-tree backend
+    (``sumtree.write``); everything else is XLA, fused by the enclosing jit.
     """
     leaf = jnp.where(applied, prio.to_leaf(priorities, cfg.alpha),
                      sumtree.leaves(state.tree)[idx])
@@ -116,30 +117,6 @@ def ingest_unfused(
         state.storage, items)
     tree = sumtree.write(state.tree, idx, leaf)
     return storage, tree
-
-
-def _ingest(
-    cfg: ReplayConfig, state: ReplayState, items: Any, priorities: jax.Array,
-    idx: jax.Array, applied: jax.Array,
-) -> tuple[Any, jax.Array]:
-    """One fused ingest: priority init + storage scatter + tree repair.
-
-    Both add modes reduce to this contract once their slot indices and lane
-    mask are computed (FIFO cursor arithmetic / ``free_slot_idx``). Dispatch
-    follows the sum-tree hot ops (``set_backend`` / ``REPRO_SUMTREE_BACKEND``):
-    the Pallas kernel does the whole thing in one VMEM round-trip on TPU
-    (``interpret`` runs it under the interpreter for CPU CI); the ``xla``
-    backend keeps :func:`ingest_unfused`, which an enclosing jit fuses into
-    one XLA program. All paths are bit-identical.
-    """
-    bk = sumtree.hot_backend(cfg.capacity)
-    if bk in ("pallas", "interpret"):
-        from repro.kernels.replay_ingest.ops import replay_ingest
-        tree, storage = replay_ingest(
-            state.tree, state.storage, idx, priorities, applied, items,
-            alpha=cfg.alpha, interpret=(bk == "interpret"))
-        return storage, tree
-    return ingest_unfused(cfg, state, items, priorities, idx, applied)
 
 
 def add_fifo(
@@ -168,7 +145,7 @@ def add_fifo(
     # re-write their slot's old leaf/row (a no-op), and since write_pos only
     # advances by n_valid the next add claims those slots anyway.
     applied = offs < n_valid
-    storage, tree = _ingest(cfg, state, items, priorities, idx, applied)
+    storage, tree = ingest(cfg, state, items, priorities, idx, applied)
     return ReplayState(
         storage=storage,
         tree=tree,
@@ -222,7 +199,7 @@ def add_alloc(
     offs = jnp.arange(batch, dtype=jnp.int32)
     # Lanes past the free-slot count would land on live slots: mask them out.
     applied = valid & (offs < num_free)
-    storage, tree = _ingest(cfg, state, items, priorities, idx, applied)
+    storage, tree = ingest(cfg, state, items, priorities, idx, applied)
     n_new = applied.sum().astype(jnp.int32)
     return ReplayState(
         storage=storage,

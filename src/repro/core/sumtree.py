@@ -15,7 +15,8 @@ fallbacks:
 * the sampling descent (``repro.kernels.sumtree_sample``) — inverse-CDF walk,
   optionally fused with the per-sample leaf-mass read;
 * the batched write (``repro.kernels.sumtree_update``) — O(B * log C)
-  incremental propagation, replacing the original O(C) full level-rebuild.
+  incremental propagation instead of the O(C) full level-rebuild (the
+  kernel still moves the whole tree, O(C) bytes, into SMEM and back).
 
 ``write`` dispatches between them via a process-wide backend switch
 (:func:`set_backend`): ``pallas`` on TPU, ``xla`` elsewhere, ``interpret``
@@ -62,12 +63,12 @@ __all__ = [
 _BACKENDS = ("pallas", "interpret", "xla")
 _backend: str | None = os.environ.get("REPRO_SUMTREE_BACKEND") or None
 
-# The one-hot kernels hold (block_b, 2C)-shaped masks in VMEM, which is only
-# viable for the small per-shard trees the replay fabric produces (the
-# paper's 2M-transition / 256-shard geometry is a 16Ki-entry tree, ~64 KiB).
-# The *auto* backend therefore only picks Pallas up to this leaf capacity
-# and falls back to XLA above it; an explicit ``set_backend("pallas")`` (or
-# env override) is honored unconditionally.
+# The kernels hold the whole tree in SMEM (1 MiB on a TPU v5e), which is
+# only viable for the small per-shard trees the replay fabric produces: at
+# this capacity the tree takes 256 KiB. The *auto* backend therefore only
+# picks Pallas up to this leaf capacity and falls back to XLA above it; an
+# explicit ``set_backend("pallas")`` (or env override) is honored
+# unconditionally.
 _PALLAS_AUTO_MAX_CAPACITY = 1 << 15
 
 
@@ -95,9 +96,9 @@ def set_backend(name: str | None) -> None:
 
 def hot_backend(cap: int) -> str:
     """Backend for one hot-op call: the auto-selected Pallas path is gated
-    on the tree being VMEM-small; explicit choices pass through. Shared by
-    every kernelized op that holds whole-tree state in VMEM (``write``,
-    ``sample_with_mass``, and ``repro.core.replay``'s fused ingest)."""
+    on the tree fitting SMEM; explicit choices pass through. Shared by the
+    kernelized ops that copy the whole tree into SMEM (``write``, which the
+    replay's adds and write-backs go through, and ``sample_with_mass``)."""
     bk = backend()
     if _backend is None and bk == "pallas" and cap > _PALLAS_AUTO_MAX_CAPACITY:
         return "xla"
@@ -234,8 +235,8 @@ def sample_two_gather(tree: jax.Array, u: jax.Array) -> tuple[jax.Array, jax.Arr
     followed by a leaf gather. Two logical gathers, but XLA fuses them into
     one program with no kernel-launch boundary — on CPU/GPU hosts this is
     the fastest shape, so it is the form the ``xla`` backend keeps (the
-    fused single-pass form only pays off where the descent kernel already
-    holds the leaf level in VMEM)."""
+    single-pass form only pays off where the descent kernel already holds
+    the tree in SMEM)."""
     idx = sample(tree, u)
     return idx, leaves(tree)[idx]
 

@@ -2,19 +2,19 @@
 
 The paper found the replay server CPU-bound and fixed it by batching all
 requests (§Contention); on TPU the analogous hot op is the batched inverse-CDF
-descent that turns a vector of mass offsets into leaf indices. Random gathers
-don't vectorize on the TPU VPU, so the descent is re-cast as a *one-hot
-select*: at every level the batch's current nodes are compared against a
-lane-iota over the (VMEM-resident) tree and the left-child masses extracted
-with a masked row-sum — an all-lanes operation instead of a serial gather.
-A replay shard's tree is small (2 * capacity f32; 64 KiB at the paper's
-2M/256-shard geometry), so the whole tree is a single VMEM block and only the
-offset batch is tiled by the grid.
+descent that turns a vector of mass offsets into leaf indices. A replay
+shard's tree is small (2 * capacity f32: 256 KiB at the Pallas path's
+largest shard, C = 2^15), so the whole tree sits in SMEM and each lane
+walks root to leaf with scalar loads: log2(C) reads per sample, where a
+one-hot select over the tree would touch all 2C nodes at every level. Every
+call copies the whole tree from HBM into an SMEM scratch at the first grid
+step (256 KiB at C = 2^15, whatever B is); the offset batch is tiled by the
+grid.
 
-The kernel also emits each sampled leaf's mass ``p^alpha`` (one more one-hot
-select at the final node), so ``replay.sample`` gets index and mass from one
-fused pass instead of a descent plus a second leaf gather. The mass is
-bitwise ``leaves(tree)[idx]``.
+The kernel also emits each sampled leaf's mass ``p^alpha`` (one more load
+at the final node), so ``replay.sample`` gets index and mass from one pass
+instead of a descent plus a second leaf gather. The mass is bitwise
+``leaves(tree)[idx]``.
 """
 
 from __future__ import annotations
@@ -24,32 +24,33 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(tree_ref, u_ref, idx_ref, mass_ref, *, depth: int, capacity: int,
-            block_b: int):
-    tree = tree_ref[...]                                    # (2C,) in VMEM
-    u = u_ref[...].astype(jnp.float32)                      # (block_b,)
-    node = jnp.ones((block_b,), jnp.int32)                  # root = 1
-    lane = jax.lax.broadcasted_iota(jnp.int32, (block_b, 2 * capacity), 1)
+def _kernel(u_ref, tree_hbm, idx_ref, mass_ref, tree, sem, *, depth: int,
+            capacity: int, block_b: int):
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        cp = pltpu.make_async_copy(tree_hbm, tree, sem.at[0])
+        cp.start()
+        cp.wait()
 
     def level(_, carry):
         node, u = carry
         left = node * 2
-        # one-hot select of tree[left] across the batch (VPU-friendly:
-        # compare + masked row-sum instead of a serial gather)
-        sel = (lane == left[:, None]).astype(jnp.float32)
-        left_mass = jnp.sum(sel * tree[None, :], axis=1)
+        left_mass = tree[left]
         go_left = u < left_mass
-        node = jnp.where(go_left, left, left + 1)
-        u = jnp.where(go_left, u, u - left_mass)
-        return node, u
+        return (jnp.where(go_left, left, left + 1),
+                jnp.where(go_left, u, u - left_mass))
 
-    node, _ = jax.lax.fori_loop(0, depth, level, (node, u))
-    idx_ref[...] = jnp.clip(node - capacity, 0, capacity - 1)
-    # fused leaf-mass read: one more one-hot select at the final node
-    sel = (lane == (idx_ref[...] + capacity)[:, None]).astype(jnp.float32)
-    mass_ref[...] = jnp.sum(sel * tree[None, :], axis=1)
+    def lane(b, carry):
+        node, _ = jax.lax.fori_loop(0, depth, level, (jnp.int32(1), u_ref[0, b]))
+        leaf = jnp.minimum(jnp.maximum(node - capacity, 0), capacity - 1)
+        idx_ref[0, b] = leaf
+        mass_ref[0, b] = tree[leaf + capacity]
+        return carry
+
+    jax.lax.fori_loop(0, block_b, lane, 0)
 
 
 def sumtree_sample_pallas(tree: jax.Array, u: jax.Array, *, block_b: int = 256,
@@ -63,27 +64,31 @@ def sumtree_sample_pallas(tree: jax.Array, u: jax.Array, *, block_b: int = 256,
     (B,) = u.shape
     block_b = min(block_b, B)
     pad = (-B) % block_b
+    u = u.astype(tree.dtype)
     if pad:
         u = jnp.pad(u, (0, pad))
     blocks = u.shape[0] // block_b
 
+    # per-lane operands as (blocks, 1, block_b), one (1, block_b) row per
+    # grid step: a 1-D block would have to match XLA's tiling of the whole
+    # vector, and a 2-D one the (8, 128) tile
+    lane_spec = pl.BlockSpec((None, 1, block_b), lambda i: (i, 0, 0),
+                             memory_space=pltpu.SMEM)
     kernel = functools.partial(_kernel, depth=depth, capacity=capacity,
                                block_b=block_b)
     idx, mass = pl.pallas_call(
         kernel,
         grid=(blocks,),
-        in_specs=[
-            pl.BlockSpec((two_c,), lambda i: (0,)),         # whole tree in VMEM
-            pl.BlockSpec((block_b,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_b,), lambda i: (i,)),
-            pl.BlockSpec((block_b,), lambda i: (i,)),
-        ],
+        in_specs=[lane_spec, pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=[lane_spec, lane_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((blocks * block_b,), jnp.int32),
-            jax.ShapeDtypeStruct((blocks * block_b,), jnp.float32),
+            jax.ShapeDtypeStruct((blocks, 1, block_b), jnp.int32),
+            jax.ShapeDtypeStruct((blocks, 1, block_b), tree.dtype),
         ],
+        scratch_shapes=[pltpu.SMEM((two_c,), tree.dtype),
+                        pltpu.SemaphoreType.DMA((1,))],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(tree, u)
-    return idx[:B], mass[:B]
+    )(u.reshape(blocks, 1, block_b), tree)
+    return idx.reshape(-1)[:B], mass.reshape(-1)[:B]

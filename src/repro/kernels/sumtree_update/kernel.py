@@ -2,31 +2,30 @@
 
 The replay server's mutation hot path: given B leaf indices and new values,
 set the leaves and restore the sum invariant along all log2(C) ancestor
-levels in one fused pass — O(B * log C) work instead of the O(C) full
-level-rebuild the XLA path originally paid per write.
+levels: B * log2(C) serial scalar steps, plus two O(C)-byte copies of the
+whole tree, instead of the O(C) full level-rebuild.
 
-Like the descent kernel (``sumtree_sample``), random gathers/scatters don't
-vectorize on the TPU VPU, so both directions are re-cast as one-hot
-all-lanes ops against the VMEM-resident tree:
+A replay shard's tree is small (2 * capacity f32: 256 KiB at the Pallas
+path's largest shard, C = 2^15), so it lives in SMEM for the whole call and
+every access is a scalar load or store at a computed address — the walk
+the XLA oracle ``repro.core.sumtree.update`` performs as vector gathers and
+scatters. Every call copies the whole tree from HBM into an SMEM scratch at
+the first grid step and back at the last (512 KiB moved at C = 2^15,
+whatever B is); the output aliases the input, so the tree is updated in
+place.
 
-* *scatter-set* — a ``(B, 2C)`` equality mask against a lane iota selects
-  each written node's column; ``jnp.where(any(mask), masked_sum, tree)``
-  commits the batch in one shot. Duplicate writers are resolved to the
-  *last* lane per node before the mask is built (matching ``.at[idx].set``
-  scatter semantics), so each column has at most one writer.
-* *gather* — child masses are read back with the same masked row-sum trick
-  the descent kernel uses.
+* *leaf writes* — lanes run in order, so a later duplicate overwrites an
+  earlier one: last-writer-wins, like ``.at[idx].set``.
+* *ancestor repair* — level by level, every lane's ancestor is recomputed
+  as ``left + right`` (the exact fp32 op ``rebuild``'s pairwise level-sum
+  performs), which keeps the kernel bit-identical to the XLA oracle and
+  transitively to scatter + ``rebuild``.
 
-Each ancestor is recomputed as ``left + right`` (the exact op ``rebuild``'s
-pairwise level-sum performs) rather than patched with a delta, which keeps
-the kernel bit-identical to the XLA oracle ``repro.core.sumtree.update`` —
-and transitively to scatter + ``rebuild``.
-
-A replay shard's tree is small (2 * capacity f32; 64 KiB at the paper's
-2M/256-shard geometry), so the whole tree lives in VMEM. The batch is tiled
-by the grid; TPU grids run sequentially, so later blocks see earlier blocks'
-writes (the output block is revisited), preserving cross-block
-last-writer-wins order.
+The batch is tiled by the grid; the steps run in order over the one SMEM
+tree, so a later block's writes land after an earlier block's
+(cross-block last-writer-wins). A block's repair recomputes every ancestor
+of its lanes from the leaves as they stand, so the tree after the last
+block equals the all-leaves-then-all-levels order of the oracle.
 """
 
 from __future__ import annotations
@@ -36,66 +35,43 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _last_writer(node: jax.Array, eligible: jax.Array, block_b: int) -> jax.Array:
-    """Mask of lanes that are the highest-numbered eligible writer of their
-    node value — the scatter's winner under duplicate indices."""
-    row = jax.lax.broadcasted_iota(jnp.int32, (block_b, block_b), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (block_b, block_b), 1)
-    shadowed = (node[None, :] == node[:, None]) & (col > row) & eligible[None, :]
-    return eligible & ~jnp.any(shadowed, axis=1)
-
-
-def _kernel(tree_ref, idx_ref, val_ref, out_ref, *, depth: int, capacity: int,
-            block_b: int):
+def _kernel(slot_ref, live_ref, val_ref, tree_hbm, out_hbm, tree, sem, *,
+            depth: int, capacity: int, block_b: int):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _():
-        out_ref[...] = tree_ref[...]
+        cp = pltpu.make_async_copy(tree_hbm, tree, sem.at[0])
+        cp.start()
+        cp.wait()
 
-    tree = out_ref[...]                                     # (2C,) in VMEM
-    idx = idx_ref[...]                                      # (block_b,)
-    val = val_ref[...].astype(jnp.float32)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (block_b, 2 * capacity), 1)
+    def leaf(b, carry):
+        @pl.when(live_ref[0, b] != 0)
+        def _():
+            tree[slot_ref[0, b] + capacity] = val_ref[0, b]
+        return carry
 
-    # numpy-style index handling, matching `.at[idx].set(mode="drop")`:
-    # negatives in [-C, -1] wrap, anything else out of [0, C) is dropped
-    idx = jnp.where(idx < 0, idx + capacity, idx)
-    in_range = (idx >= 0) & (idx < capacity)
-    node = jnp.clip(idx, 0, capacity - 1) + capacity
+    jax.lax.fori_loop(0, block_b, leaf, 0)
 
-    # Leaf level: last in-range writer per leaf sets its value.
-    wins = _last_writer(node, in_range, block_b)
-    sel = (lane == node[:, None]) & wins[:, None]
-    tree = jnp.where(jnp.any(sel, axis=0),
-                     jnp.sum(jnp.where(sel, val[:, None], 0.0), axis=0),
-                     tree)
+    # Every lane repairs its path, dropped lanes included (their clipped
+    # slot's path is recomputed unchanged) — the oracle's order exactly.
+    def level(lv, carry):
+        def lane(b, carry):
+            node = (slot_ref[0, b] + capacity) >> (lv + 1)
+            tree[node] = tree[2 * node] + tree[2 * node + 1]
+            return carry
+        return jax.lax.fori_loop(0, block_b, lane, carry)
 
-    # Ancestor levels: recompute each touched parent as left + right. All
-    # lanes sharing a parent compute the identical value, and even a lane
-    # whose leaf write was dropped writes an invariant-restoring value — but
-    # the one-hot sum needs exactly one writer per column, so a single
-    # representative lane is elected per node.
-    all_lanes = jnp.ones((block_b,), bool)
+    jax.lax.fori_loop(0, depth, level, 0)
 
-    def level(_, carry):
-        tree, node = carry
-        node = node >> 1
-        lsel = (lane == (2 * node)[:, None]).astype(jnp.float32)
-        rsel = (lane == (2 * node + 1)[:, None]).astype(jnp.float32)
-        pval = (jnp.sum(lsel * tree[None, :], axis=1)
-                + jnp.sum(rsel * tree[None, :], axis=1))
-        rep = _last_writer(node, all_lanes, block_b)
-        sel = (lane == node[:, None]) & rep[:, None]
-        tree = jnp.where(jnp.any(sel, axis=0),
-                         jnp.sum(jnp.where(sel, pval[:, None], 0.0), axis=0),
-                         tree)
-        return tree, node
-
-    tree, _ = jax.lax.fori_loop(0, depth, level, (tree, node))
-    out_ref[...] = tree
+    @pl.when(i == pl.num_programs(0) - 1)
+    def _():
+        cp = pltpu.make_async_copy(tree, out_hbm, sem.at[0])
+        cp.start()
+        cp.wait()
 
 
 def sumtree_update_pallas(tree: jax.Array, idx: jax.Array, values: jax.Array,
@@ -111,27 +87,39 @@ def sumtree_update_pallas(tree: jax.Array, idx: jax.Array, values: jax.Array,
     capacity = two_c // 2
     depth = capacity.bit_length() - 1
     (B,) = idx.shape
-    block_b = max(1, min(block_b, B)) if B else 1
-    pad = (-B) % block_b if B else block_b
+    if B == 0:
+        return tree
+    idx = idx.astype(jnp.int32)
+    idx = jnp.where(idx < 0, idx + capacity, idx)
+    live = ((idx >= 0) & (idx < capacity)).astype(jnp.int32)
+    slot = jnp.clip(idx, 0, capacity - 1)
+    values = values.astype(tree.dtype)
+    block_b = min(block_b, B)
+    pad = (-B) % block_b
     if pad:
-        # padding lanes carry an always-dropped index (>= C; negative
-        # sentinels would wrap numpy-style and hit a real leaf)
-        idx = jnp.pad(idx, (0, pad), constant_values=capacity)
-        values = jnp.pad(values, (0, pad))
-    blocks = idx.shape[0] // block_b
+        # padding lanes repeat the last lane: the same write, once more
+        slot, live, values = (jnp.pad(x, (0, pad), mode="edge")
+                              for x in (slot, live, values))
+    blocks = slot.shape[0] // block_b
 
+    # per-lane operands as (blocks, 1, block_b), one (1, block_b) row per
+    # grid step: a 1-D block would have to match XLA's tiling of the whole
+    # vector, and a 2-D one the (8, 128) tile
+    lane_spec = pl.BlockSpec((None, 1, block_b), lambda i: (i, 0, 0),
+                             memory_space=pltpu.SMEM)
     kernel = functools.partial(_kernel, depth=depth, capacity=capacity,
                                block_b=block_b)
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid=(blocks,),
-        in_specs=[
-            pl.BlockSpec((two_c,), lambda i: (0,)),         # whole tree in VMEM
-            pl.BlockSpec((block_b,), lambda i: (i,)),
-            pl.BlockSpec((block_b,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((two_c,), lambda i: (0,)),   # revisited per block
+        in_specs=[lane_spec, lane_spec, lane_spec,
+                  pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=pl.BlockSpec(memory_space=pltpu.HBM),
         out_shape=jax.ShapeDtypeStruct((two_c,), tree.dtype),
+        scratch_shapes=[pltpu.SMEM((two_c,), tree.dtype),
+                        pltpu.SemaphoreType.DMA((1,))],
+        input_output_aliases={3: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(tree, idx.astype(jnp.int32), values.astype(tree.dtype))
-    return out
+    )(*(x.reshape(blocks, 1, block_b) for x in (slot, live, values)), tree)

@@ -32,8 +32,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import INPUT_SHAPES, shape_applicable
-from repro.launch import (hlo_analysis, mesh as mesh_lib,
-                          sharding as shard_lib, steps as steps_lib)
+from repro.launch import (hlo_analysis, sharding as shard_lib,
+                          steps as steps_lib)
 from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16, data_axes,
                                make_production_mesh, num_chips)
 from repro.models import registry, transformer
@@ -41,15 +41,6 @@ from repro.optim import optimizers as optim
 
 ARTIFACT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                             "benchmarks", "artifacts")
-
-
-def cost_dict(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions: older
-    releases return a one-element list of dicts, newer ones a flat dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
 
 
 def probe_flops_scope(mesh) -> str:
@@ -62,7 +53,7 @@ def probe_flops_scope(mesh) -> str:
     sa = jax.NamedSharding(mesh, jax.sharding.PartitionSpec(D, None))
     sb = jax.NamedSharding(mesh, jax.sharding.PartitionSpec(None, "model"))
     compiled = jax.jit(lambda a, b: a @ b, in_shardings=(sa, sb)).lower(a, b).compile()
-    flops = float(cost_dict(compiled).get("flops", 0.0))
+    flops = float(compiled.cost_analysis().get("flops", 0.0))
     expected_global = 2.0 * M * K * N
     return "global" if flops > expected_global / 2 else "per_device"
 
@@ -199,7 +190,7 @@ def run_combo(arch: str, shape_name: str, multi_pod: bool,
 
     # 1) full production compile — the "it lowers, compiles and fits" proof
     t0 = time.time()
-    with mesh_lib.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered, full_cfg = build_lowered(cfg, shape, mesh,
                                           overrides=overrides)
     t_lower = time.time() - t0
@@ -211,11 +202,11 @@ def run_combo(arch: str, shape_name: str, multi_pod: bool,
     # 2) (k, 2k)-layer unrolled probes — exact cost/collective accounting,
     #    linearly extrapolated to n_layers
     def probe_costs(layers: int) -> dict:
-        with mesh_lib.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             plow, _ = build_lowered(cfg, shape, mesh, probe_layers=layers,
                                     overrides=overrides)
         pcomp = plow.compile()
-        cost = cost_dict(pcomp)
+        cost = pcomp.cost_analysis()
         coll = hlo_analysis.parse_collectives(pcomp.as_text())
         return {"flops": float(cost.get("flops", 0.0)),
                 "bytes": float(cost.get("bytes accessed", 0.0)),

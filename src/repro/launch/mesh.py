@@ -15,20 +15,11 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """Version-compatible mesh construction: jax >= 0.5 wants explicit
-    ``axis_types``; on older jax ``Auto`` is implicit and the enum absent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-
-
-def set_mesh(mesh):
-    """Version-compatible ambient-mesh context: ``jax.set_mesh`` on jax >=
-    0.6; on older releases the ``Mesh`` object is itself the context
-    manager that installs the resource environment."""
-    setter = getattr(jax, "set_mesh", None)
-    return setter(mesh) if setter is not None else mesh
+    """``jax.make_mesh`` with every axis ``Auto`` (JAX's default is
+    ``Explicit``): shardings inside ``shard_map`` and ``jit`` stay the
+    compiler's to propagate."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from repro.checkpoint import checkpoint as ckpt_lib
 from repro.core import apex, replay as replay_lib, sequence_replay as seqrep
 from repro.data import pipeline as data_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry, transformer
 from repro.obs import log as obslog
 from repro.optim import optimizers as optim
@@ -40,6 +41,8 @@ from repro.runtime import AsyncConfig, run_async
 
 
 def run_apex(preset, iterations: int, log_every: int, ckpt_dir: str | None):
+    """Lockstep driver; returns the final state and the last iteration's
+    metrics."""
     optimizer = preset.make_optimizer()
     init_fn, step_fn = apex.make_train_fn(
         preset.apex, preset.env, preset.agent, optimizer)
@@ -60,7 +63,7 @@ def run_apex(preset, iterations: int, log_every: int, ckpt_dir: str | None):
                           {"params": state.params,
                            "opt_state": state.opt_state,
                            "learner_step": state.learner_step}, step=it + 1)
-    return state
+    return state, metrics
 
 
 def run_apex_async(preset, learner_steps: int, actor_threads: int,
@@ -138,7 +141,8 @@ def run_apex_async(preset, learner_steps: int, actor_threads: int,
     if res.gateway_stats is not None:
         g = res.gateway_stats
         obslog.emit("gateway", actor_procs=int(s["actor_procs"]),
-                    conns=g.connections, shm_conns=g.shm_connections,
+                    conns=g.connections, cpu_clients=g.cpu_clients,
+                    shm_conns=g.shm_connections,
                     blocks_in=g.blocks_in, transitions_in=g.transitions_in,
                     param_sends=g.param_sends,
                     mb_in=round(g.bytes_in / 1e6, 1))
@@ -229,7 +233,8 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--ckpt-dir")
     ap.add_argument("--full", action="store_true",
-                    help="paper-scale preset (mesh required)")
+                    help="paper-scale preset: one shard's share of the "
+                         "paper geometry, run on one device")
     ap.add_argument("--runtime", choices=("sync", "async"), default="sync",
                     help="sync: lockstep act/learn alternation; async: "
                          "decoupled actor threads + replay service + learner "
@@ -530,9 +535,12 @@ def validate_args(ap: argparse.ArgumentParser,
     return args
 
 
-def main():
+def main(argv: list[str] | None = None):
+    """CLI entry point; returns what the selected run returns (the final
+    train state, or the async runtime's result)."""
     ap = build_parser()
-    args = validate_args(ap, ap.parse_args())
+    args = validate_args(ap, ap.parse_args(argv))
+    enable_compile_cache()
 
     def run_preset(preset):
         if args.runtime == "async":
@@ -540,34 +548,31 @@ def main():
                 ap.error(f"--replay-shards {args.replay_shards} must divide "
                          f"the preset batch size {preset.apex.batch_size} "
                          "(equal per-shard sample quotas)")
-            run_apex_async(preset, args.iterations, args.actor_threads,
-                           args.ckpt_dir, args.replay_shards,
-                           args.inference_batching, args.actor_procs,
-                           args.learn_batches, args.wire_quantize_obs,
-                           args.sample_staging, args.learner_remote,
-                           args.serve_sampling, args.gateway_port,
-                           args.gateway_host, args.transport,
-                           args.wire_quantize_prios,
-                           args.wire_quantize_params,
-                           args.ingest_staging,
-                           args.add_queue_depth, args.sample_queue_depth,
-                           args.metrics_dir, args.trace_sample_rate,
-                           args.checkpoint_dir, args.checkpoint_every_s,
-                           args.resume, args.inference_mode,
-                           args.serve_policy)
-        else:
-            run_apex(preset, args.iterations, args.log_every, args.ckpt_dir)
+            return run_apex_async(preset, args.iterations, args.actor_threads,
+                                  args.ckpt_dir, args.replay_shards,
+                                  args.inference_batching, args.actor_procs,
+                                  args.learn_batches, args.wire_quantize_obs,
+                                  args.sample_staging, args.learner_remote,
+                                  args.serve_sampling, args.gateway_port,
+                                  args.gateway_host, args.transport,
+                                  args.wire_quantize_prios,
+                                  args.wire_quantize_params,
+                                  args.ingest_staging,
+                                  args.add_queue_depth, args.sample_queue_depth,
+                                  args.metrics_dir, args.trace_sample_rate,
+                                  args.checkpoint_dir, args.checkpoint_every_s,
+                                  args.resume, args.inference_mode,
+                                  args.serve_policy)
+        return run_apex(preset, args.iterations, args.log_every,
+                        args.ckpt_dir)
 
     if args.mode == "apex-dqn":
         from repro.configs import apex_dqn
-        preset = apex_dqn.full() if args.full else apex_dqn.reduced()
-        run_preset(preset)
-    elif args.mode == "apex-dpg":
+        return run_preset(apex_dqn.full() if args.full else apex_dqn.reduced())
+    if args.mode == "apex-dpg":
         from repro.configs import apex_dpg
-        preset = apex_dpg.full() if args.full else apex_dpg.reduced()
-        run_preset(preset)
-    else:
-        run_llm(args.arch, args.iterations, args.log_every, args.ckpt_dir)
+        return run_preset(apex_dpg.full() if args.full else apex_dpg.reduced())
+    return run_llm(args.arch, args.iterations, args.log_every, args.ckpt_dir)
 
 
 if __name__ == "__main__":

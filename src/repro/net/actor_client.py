@@ -195,7 +195,8 @@ class RemoteActorLoop:
         self._conn.send(wire.HELLO, wire.encode_json(
             {"actor_id": self.spec.actor_id,
              "protocol": wire.PROTOCOL_VERSION,
-             "reconnects": self.stats["reconnects"]}))
+             "reconnects": self.stats["reconnects"],
+             "platform": jax.default_backend()}))
         if self.spec.policy is None:
             self._pull_params(self._conn)
         # thin-client mode never pulls: the policy gateway's engine holds
@@ -364,7 +365,12 @@ def run_remote_actor(spec: RemoteActorSpec) -> dict:
     """Process entry point (importable, so ``multiprocessing`` spawn and
     ``launch/train.py --actor-procs`` can target it). A gateway that is
     already gone — e.g. the learner finished while this process was still
-    compiling — is a clean exit, not a crash."""
+    compiling — is a clean exit, not a crash.
+
+    The process runs JAX on the CPU, as the paper's actors do: the parent
+    that spawned it may hold the accelerator, which belongs to one process
+    at a time, so a child that reached for it would fail or hang."""
+    jax.config.update("jax_platforms", "cpu")
     if spec.pin_cpu is not None and hasattr(os, "sched_setaffinity"):
         # Before the first jax op: XLA's intra-op threads spawn lazily and
         # inherit this affinity, so the whole process stays on one core.

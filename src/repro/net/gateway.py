@@ -116,6 +116,9 @@ class GatewayStats:
                                 # some in-flight priority frames
     act_requests: int = 0       # policy-plane rollouts served (ACT_RESULT
                                 # replies; a STOP answer is not counted)
+    cpu_clients: int = 0        # first HELLOs from clients whose JAX runs
+                                # on the CPU (actor processes keep off the
+                                # accelerator the serving process holds)
 
 
 class ReplayGateway:
@@ -349,6 +352,8 @@ class ReplayGateway:
                         # dialed back in — count the comeback, not its
                         # lifetime total (each HELLO reports cumulative).
                         self._bump(client_reconnects=1)
+                    elif hello.get("platform") == "cpu":
+                        self._bump(cpu_clients=1)
                 elif msg_type == wire.BYE:
                     stats = wire.decode_json(payload)
                     self._bump(

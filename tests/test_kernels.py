@@ -6,13 +6,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import priority as prio, sumtree
+from repro.core import priority as prio, replay, sumtree
 from repro.core.nstep import from_trajectory
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.kernels.nstep_return.ops import nstep_return
-from repro.kernels.replay_ingest.ops import replay_ingest
-from repro.kernels.replay_ingest.ref import replay_ingest_ref
 from repro.kernels.sumtree_sample.ops import (sumtree_sample,
                                               sumtree_sample_with_mass)
 from repro.kernels.sumtree_update.ops import sumtree_update
@@ -110,8 +108,40 @@ def test_sumtree_update_kernel_cross_block_last_writer_wins():
     assert float(sumtree.leaves(got)[5]) == 6.0
 
 
+def replay_ingest_ref(tree, storage, idx, priorities, applied, items, *,
+                      alpha: float = prio.PRIORITY_EXPONENT):
+    """Pure-jnp oracle of the replay ingest: leaf values, storage scatter,
+    tree write by the XLA incremental update. All "old" values (masked
+    lanes' leaves and rows) are gathered from the *input* state before any
+    scatter lands, and duplicate slots resolve last-writer-wins."""
+    leaf = jnp.where(applied, prio.to_leaf(priorities, alpha),
+                     sumtree.leaves(tree)[idx])
+    new_storage = jax.tree.map(
+        lambda buf, x: buf.at[idx].set(
+            jnp.where(jnp.expand_dims(applied, tuple(range(1, x.ndim))),
+                      x.astype(buf.dtype), buf[idx])),
+        storage, items)
+    return sumtree.update(tree, idx, leaf), new_storage
+
+
+def replay_ingest(tree, storage, idx, priorities, applied, items):
+    """``replay.ingest`` with its tree write on the update kernel, run
+    under the Pallas interpreter -> (new_tree, new_storage)."""
+    cfg = replay.ReplayConfig(capacity=sumtree.capacity(tree))
+    zero = jnp.zeros((), jnp.int32)
+    state = replay.ReplayState(storage, tree, zero, zero, zero)
+    saved = sumtree._backend
+    sumtree.set_backend("interpret")
+    try:
+        new_storage, new_tree = replay.ingest(cfg, state, items, priorities,
+                                              idx, applied)
+    finally:
+        sumtree.set_backend(saved)
+    return new_tree, new_storage
+
+
 def _ingest_case(cap, B, seed):
-    """Random fused-ingest inputs: a partially-filled tree, a mixed-dtype
+    """Random ingest inputs: a partially-filled tree, a mixed-dtype
     storage pytree (matrix, int32 vector, scalar leaf), duplicate slots,
     overflow lanes (idx == C, the alloc path's drop sentinel) and a mixed
     applied mask."""
@@ -144,31 +174,27 @@ def _assert_ingest_equal(got, want):
                                       np.asarray(want_storage[k]), err_msg=k)
 
 
-@pytest.mark.parametrize("cap,B,block", [(64, 32, 32), (256, 100, 64),
-                                         (32, 7, 8), (64, 64, 16),
-                                         (16, 16, 1)])
-def test_replay_ingest_matches_ref(cap, B, block):
-    """Fused ingest (priority init + storage scatter + tree repair) ==
-    the three-dispatch oracle, bit-for-bit, across block geometries."""
+@pytest.mark.parametrize("cap,B", [(64, 32), (256, 100), (32, 7), (64, 64),
+                                   (16, 16)])
+def test_replay_ingest_matches_ref(cap, B):
+    """Ingest (priority init + storage scatter + tree repair by the update
+    kernel) == the XLA oracle, bit-for-bit."""
     tree, storage, idx, prios, applied, items = _ingest_case(cap, B, cap + B)
     want = replay_ingest_ref(tree, storage, idx, prios, applied, items)
-    got = replay_ingest(tree, storage, idx, prios, applied, items,
-                        block_b=block, interpret=True)
+    got = replay_ingest(tree, storage, idx, prios, applied, items)
     _assert_ingest_equal(got, want)
 
 
 def test_replay_ingest_index_handling():
-    """Scatter-faithful index handling (and the block padding path): -1
-    wraps to C-1, idx == C (the alloc overflow sentinel) drops without
-    touching slot 0."""
+    """Scatter-faithful index handling: -1 wraps to C-1, idx == C (the
+    alloc overflow sentinel) drops without touching slot 0."""
     cap = 8
     tree, storage, _, _, _, items = _ingest_case(cap, 3, 7)
-    idx = jnp.array([-1, cap, 2], jnp.int32)   # block_b=2: exercises padding
+    idx = jnp.array([-1, cap, 2], jnp.int32)
     prios = jnp.array([9.0, 8.0, 7.0], jnp.float32)
     applied = jnp.array([True, True, True])
     want = replay_ingest_ref(tree, storage, idx, prios, applied, items)
-    got = replay_ingest(tree, storage, idx, prios, applied, items,
-                        block_b=2, interpret=True)
+    got = replay_ingest(tree, storage, idx, prios, applied, items)
     _assert_ingest_equal(got, want)
     got_tree, got_storage = got
     # -1 wrapped: slot C-1 carries lane 0's item; the overflow lane changed
@@ -180,18 +206,18 @@ def test_replay_ingest_index_handling():
 
 
 def test_replay_ingest_cross_block_last_writer_wins():
-    """Duplicate slots split across grid blocks resolve like the XLA
-    scatter: the later lane wins — and a masked later duplicate re-writes
-    the *original* row/leaf (gather-all-then-scatter), not the earlier
-    lane's value."""
+    """Duplicate slots resolve like the XLA scatter: the later lane wins —
+    and a masked later duplicate re-writes the *original* row/leaf
+    (gather-all-then-scatter), not the earlier lane's value. Duplicates
+    split across the update kernel's grid blocks are
+    ``test_sumtree_update_kernel_cross_block_last_writer_wins``."""
     cap = 8
     tree, storage, _, _, _, items = _ingest_case(cap, 4, 11)
-    idx = jnp.array([5, 1, 5, 5], jnp.int32)   # block_b=2: dup spans blocks
+    idx = jnp.array([5, 1, 5, 5], jnp.int32)
     prios = jnp.array([2.0, 3.0, 4.0, 6.0], jnp.float32)
     applied = jnp.array([True, True, True, True])
     want = replay_ingest_ref(tree, storage, idx, prios, applied, items)
-    got = replay_ingest(tree, storage, idx, prios, applied, items,
-                        block_b=2, interpret=True)
+    got = replay_ingest(tree, storage, idx, prios, applied, items)
     _assert_ingest_equal(got, want)
     got_tree, got_storage = got
     assert float(sumtree.leaves(got_tree)[5]) == float(
@@ -204,8 +230,7 @@ def test_replay_ingest_cross_block_last_writer_wins():
     idx2 = jnp.array([5, 5], jnp.int32)
     items2 = jax.tree.map(lambda x: x[:2], items)
     want2 = replay_ingest_ref(tree, storage, idx2, prios[:2], applied2, items2)
-    got2 = replay_ingest(tree, storage, idx2, prios[:2], applied2, items2,
-                         block_b=1, interpret=True)
+    got2 = replay_ingest(tree, storage, idx2, prios[:2], applied2, items2)
     _assert_ingest_equal(got2, want2)
     np.testing.assert_array_equal(np.asarray(got2[1]["obs"][5]),
                                   np.asarray(storage["obs"][5]))

@@ -53,6 +53,7 @@ def test_remote_loop_streams_blocks_and_pulls_params():
     assert gw.error is None and fabric.error is None
     gsnap = gw.snapshot()
     assert gsnap.client_rollouts == 6           # BYE counters merged
+    assert gsnap.cpu_clients == 1               # HELLO named its platform
 
 
 def test_remote_loop_blocks_on_full_inflight_window():
@@ -156,6 +157,7 @@ def test_run_async_two_actor_procs_end_to_end():
     assert s["replay_size"] > 0
     assert res.gateway_stats is not None
     assert res.gateway_stats.connections == 2
+    assert res.gateway_stats.cpu_clients == 2   # children keep off the chip
     if transport == "shm":
         assert res.gateway_stats.shm_connections == 2
     assert res.gateway_stats.blocks_in > 0

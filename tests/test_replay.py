@@ -125,7 +125,7 @@ def test_min_fill_gate():
     assert bool(replay.can_sample(CFG, state))
 
 
-# --- fused ingest: kernel path bit-identical to the three-dispatch path -----
+# --- ingest: update-kernel path bit-identical to the XLA path ---------------
 
 @contextlib.contextmanager
 def pinned_backend(name):
@@ -156,9 +156,9 @@ def assert_replay_states_identical(got, want):
     seed=st.integers(0, 10**6),
 )
 def test_fused_ingest_bit_identical(mode, batch, prefill, seed):
-    """add_fifo/add_alloc through the fused Pallas ingest kernel (interpret
-    on CPU) must be bit-identical to the unfused XLA three-dispatch path —
-    across wrap-around, duplicate slots, overflow lanes and valid masks."""
+    """add_fifo/add_alloc with the tree write on the Pallas update kernel
+    (interpret on CPU) must be bit-identical to the all-XLA path — across
+    wrap-around, duplicate slots, overflow lanes and valid masks."""
     cfg = replay.ReplayConfig(capacity=32, soft_capacity=24, min_fill=1)
     state = replay.init(cfg, {"x": jnp.zeros(()),
                               "y": jnp.zeros((3,), jnp.int32)})
@@ -183,7 +183,7 @@ def test_fused_ingest_bit_identical(mode, batch, prefill, seed):
 def test_fused_ingest_full_capacity_add(mode):
     """A block exactly the size of the buffer, onto an empty state and onto
     a full one: fifo wraps/overwrites everything, alloc drops every overflow
-    lane — both bit-identical to the unfused path."""
+    lane — both bit-identical to the all-XLA path."""
     cap = 32
     cfg = replay.ReplayConfig(capacity=cap, soft_capacity=24, min_fill=1)
     empty = replay.init(cfg, {"x": jnp.zeros(()),
@@ -203,7 +203,7 @@ def test_fused_ingest_full_capacity_add(mode):
 
 
 def test_fused_alloc_overflow_drops_on_kernel_path():
-    """The overflow sentinel (idx == C) must drop inside the kernel too —
+    """The overflow sentinel (idx == C) must drop on the kernel path too —
     live slots (slot 0 in particular) keep their rows and leaves."""
     cfg = replay.ReplayConfig(capacity=16, soft_capacity=12, min_fill=1)
     state = replay.init(cfg, {"x": jnp.zeros(()),
