@@ -180,7 +180,7 @@ def learner_phase(cfg: ApexConfig, agent, optimizer, state: ApexState,
 
         def do_update(st: ApexState) -> tuple[ApexState, dict]:
             s_rng, e_rng = jax.random.split(rng)
-            batch = replay_lib.sample(rcfg, st.replay, s_rng, cfg.batch_size)
+            batch = phases.replay_sample(cfg, st.replay, s_rng, cfg.batch_size)
             weights = _global_is_weights(cfg, batch, st.replay.size, axis_name)
             lslice = phases.LearnerSlice(
                 params=st.params, target_params=st.target_params,
@@ -247,22 +247,26 @@ def make_train_fn(cfg: ApexConfig, env, agent, optimizer, mesh=None,
     Without a mesh: single-shard jitted loop (tests/examples). With a mesh:
     ``shard_map`` over the data axis — replicated learner state, per-shard
     replay/envs; collectives are the gradient pmean + the IS/min-fill scalars.
+    Either way the two programs are named ``jit_apex_init`` and
+    ``jit_apex_step``.
     """
     if mesh is None:
-        init_fn = jax.jit(
-            lambda rng: init_state(cfg, env, agent, optimizer, rng, 0))
-        step_fn = jax.jit(
-            lambda st: train_iteration(cfg, env, agent, optimizer, st, 0, None))
-        return init_fn, step_fn
+        def apex_init(rng):
+            return init_state(cfg, env, agent, optimizer, rng, 0)
+
+        def apex_step(st):
+            return train_iteration(cfg, env, agent, optimizer, st, 0, None)
+
+        return jax.jit(apex_init), jax.jit(apex_step)
 
     shard_map = functools.partial(jax.shard_map, check_vma=False)
 
-    def per_shard_init(rng):
+    def apex_init(rng):
         sid = jax.lax.axis_index(data_axis)
         st = init_state(cfg, env, agent, optimizer, rng, sid)
         return _add_leading(st)
 
-    def per_shard_step(st):
+    def apex_step(st):
         sid = jax.lax.axis_index(data_axis)
         st = _strip_leading(st)
         st, metrics = train_iteration(cfg, env, agent, optimizer, st, sid, data_axis)
@@ -278,9 +282,9 @@ def make_train_fn(cfg: ApexConfig, env, agent, optimizer, mesh=None,
 
     specs = state_specs()
     init_fn = jax.jit(shard_map(
-        per_shard_init, mesh=mesh, in_specs=P(), out_specs=specs))
+        apex_init, mesh=mesh, in_specs=P(), out_specs=specs))
     step_fn = jax.jit(shard_map(
-        per_shard_step, mesh=mesh, in_specs=(specs,),
+        apex_step, mesh=mesh, in_specs=(specs,),
         out_specs=(specs, P())))
     return init_fn, step_fn
 

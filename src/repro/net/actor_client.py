@@ -123,8 +123,10 @@ class RemoteActorLoop:
     def __init__(self, spec: RemoteActorSpec):
         self.spec = spec
         cfg, env, agent = spec.cfg, spec.env, spec.agent
-        self._act = jax.jit(lambda p, sl, sid: phases.act_phase(
-            cfg, env, agent, p, sl, sid))
+        def actor_rollout(p, sl, sid):  # jit_actor_rollout, as in the runner
+            return phases.act_phase(cfg, env, agent, p, sl, sid)
+
+        self._act = jax.jit(actor_rollout)
         self._sync_period = (spec.param_sync_period
                              if spec.param_sync_period is not None
                              else cfg.param_sync_period)

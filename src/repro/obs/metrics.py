@@ -139,6 +139,13 @@ class Histogram:
     def mean(self) -> float:
         return self._sum / self._count if self._count else 0.0
 
+    def bucket_counts(self) -> list[int]:
+        """A copy of the per-bucket counts (bucket ``i`` spans
+        ``[2**(i/4), 2**((i+1)/4))``): subtract two copies for the
+        histogram of what was recorded in between."""
+        with self._lock:
+            return list(self._counts)
+
     def percentile(self, q: float) -> float:
         """Interpolated q-th percentile (q in [0, 100]). Exact to within
         one bucket ratio (~19%): the true quantile lives in the bucket the

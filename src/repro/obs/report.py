@@ -3,15 +3,22 @@
 Renders the JSONL a :class:`repro.obs.sink.JsonlSink` wrote during a run
 into a per-stage bottleneck table:
 
-* **stage table** — for each pipeline stage (actor, gateway, add,
-  sample, learn, writeback): span count, sustained rate over the
-  observed window, and p50/p95/p99 of the stage's own duration.
-* **inter-stage gaps** — wall-time between consecutive stages of the
-  same trace id (actor→gateway, gateway→add, sample→learn,
-  learn→writeback): this is where a bottleneck shows up as queue time
-  that no single stage's duration explains.
+* **stage table** — for each pipeline stage (the runtime's span names,
+  ``actor/rollout`` through ``shard/writeback``, plus the gateway and
+  sample-source stages): span count, sustained rate over the observed
+  window, and p50/p95/p99 of the stage's own duration
+  (``end_ns - start_ns``).
+* **inter-stage gaps** — for consecutive stages of the same trace id,
+  the time from the first stage's end to the next one's start
+  (actor add → shard add, learner write-back → shard write-back, ...):
+  this is where a bottleneck shows up as queue time that no single
+  stage's duration explains.
 * **queue depths** — last-seen gauge values (shard add/sample queues,
   staged prefetch depth, replay size).
+* **wait shares** — each ``*_wait_us`` counter (one thread's time
+  blocked: ``learner/batch_wait_us``, each shard owner's
+  ``shardN/sync_wait_us``) as a share of that thread's time between the
+  first and the last snapshot.
 * **stall counters** — starvation and backpressure totals (learner
   starved polls, actor add-blocked, gateway add retries).
 * **recovery events** — the fault-tolerance plane's counters (actor
@@ -33,15 +40,25 @@ from .sink import METRICS_FILE, SPANS_FILE
 
 # Canonical pipeline order; report rows render in this order with any
 # unknown stages appended (future planes report in without edits here).
-STAGE_ORDER = ["actor", "gateway", "add", "sample", "learn", "writeback"]
+STAGE_ORDER = ["actor/param_refresh", "actor/rollout", "actor/add", "gateway",
+               "shard/add", "sample", "learner/get_batch", "fabric/merge",
+               "learner/step", "learner/write_back", "fabric/route_writeback",
+               "shard/writeback", "shard/sync", "learner/publish"]
 
-# Consecutive same-trace-id stage pairs whose wall-time gap is queue
-# time between planes. (add → sample is NOT a pair: a block's add id and
-# a batch's sample id are different traces by design.)
-GAP_PAIRS = [("actor", "gateway"), ("gateway", "add"),
-             ("sample", "learn"), ("learn", "writeback")]
+# Consecutive same-trace-id stage pairs whose gap (the first one's end to
+# the second one's start) is queue time between planes. (A block's add id
+# and a batch's sample id are different traces by design, so no pair
+# crosses from ingest to consume.)
+GAP_PAIRS = [("actor/rollout", "actor/add"), ("actor/add", "shard/add"),
+             ("gateway", "shard/add"), ("sample", "learner/step"),
+             ("learner/step", "learner/write_back"),
+             ("learner/write_back", "shard/writeback")]
 
 _STALL_TOKENS = ("starved", "backpressure", "blocked", "retries", "dropped")
+
+# A counter of microseconds one thread spent blocked, reported as a share
+# of that thread's time.
+_WAIT_SUFFIX = "_wait_us"
 
 # Counters whose names carry these tokens are recovery events: the
 # fault-tolerance plane reporting restarts, reconnects, and snapshots.
@@ -79,17 +96,19 @@ def _percentile(values: list[float], q: float) -> float:
 def load_report(directory: str) -> dict:
     """Aggregate a metrics dir into the report's data model."""
     metrics = _read_jsonl(os.path.join(directory, METRICS_FILE))
-    spans = _read_jsonl(os.path.join(directory, SPANS_FILE))
+    spans = [s for s in _read_jsonl(os.path.join(directory, SPANS_FILE))
+             if "start_ns" in s and "end_ns" in s]
 
     # --- stage table -----------------------------------------------------
     by_stage: dict[str, list[dict]] = {}
     for span in spans:
         by_stage.setdefault(span.get("stage", "?"), []).append(span)
-    ts_all = [s["ts"] for s in spans if "ts" in s]
-    window_s = max(max(ts_all) - min(ts_all), 1e-9) if ts_all else 0.0
+    window_s = (max((max(s["end_ns"] for s in spans)
+                     - min(s["start_ns"] for s in spans)) / 1e9, 1e-9)
+                if spans else 0.0)
     stages = {}
     for stage, group in by_stage.items():
-        durs = [s["dur_us"] for s in group if "dur_us" in s]
+        durs = [(s["end_ns"] - s["start_ns"]) / 1e3 for s in group]
         stages[stage] = {
             "count": len(group),
             "rate_hz": len(group) / window_s if window_s else 0.0,
@@ -99,18 +118,21 @@ def load_report(directory: str) -> dict:
         }
 
     # --- inter-stage gaps ------------------------------------------------
-    by_tid: dict[int, dict[str, float]] = {}
+    # per trace id and stage, the earliest span: the gap measures when the
+    # stage first touched this trace (a batch's write-back fans out to
+    # every shard).
+    by_tid: dict[int, dict[str, tuple[int, int]]] = {}
     for span in spans:
         tid = span.get("trace_id")
         if tid:
-            # first occurrence wins: the gap measures when the stage
-            # first touched this trace.
-            by_tid.setdefault(tid, {}).setdefault(
-                span.get("stage", "?"), span.get("ts", 0.0))
+            st = by_tid.setdefault(tid, {})
+            stage = span.get("stage", "?")
+            if stage not in st or span["start_ns"] < st[stage][0]:
+                st[stage] = (span["start_ns"], span["end_ns"])
     gaps = {}
     for src, dst in GAP_PAIRS:
-        deltas = [(st[dst] - st[src]) * 1e6 for st in by_tid.values()
-                  if src in st and dst in st and st[dst] >= st[src]]
+        deltas = [(st[dst][0] - st[src][1]) / 1e3 for st in by_tid.values()
+                  if src in st and dst in st and st[dst][0] >= st[src][1]]
         if deltas:
             gaps[f"{src}->{dst}"] = {
                 "count": len(deltas),
@@ -133,6 +155,17 @@ def load_report(directory: str) -> dict:
         if any(tok in name for tok in _RECOVERY_TOKENS):
             recovery[name] = val
 
+    # --- wait shares: microseconds blocked per microsecond of the span
+    # between the first and the last snapshot ----------------------------
+    waits = {}
+    first = metrics[0] if metrics else {}
+    span_s = last.get("ts", 0.0) - first.get("ts", 0.0)
+    if span_s > 0:
+        before = first.get("counters", {})
+        for name, val in counters.items():
+            if name.endswith(_WAIT_SUFFIX):
+                waits[name] = (val - before.get(name, 0)) / (span_s * 1e6)
+
     # --- inference plane -------------------------------------------------
     # The wave scheduler's padding tax, made honest: lifetime fraction of
     # dispatched lanes that were replicated padding (recomputed duplicate
@@ -149,7 +182,8 @@ def load_report(directory: str) -> dict:
     return {"directory": directory, "window_s": window_s,
             "num_spans": len(spans), "num_snapshots": len(metrics),
             "stages": stages, "gaps": gaps, "gauges": gauges,
-            "counters": counters, "stalls": stalls, "recovery": recovery,
+            "counters": counters, "waits": waits, "stalls": stalls,
+            "recovery": recovery,
             "inference": inference,
             "histograms": dict(last.get("histograms", {}))}
 
@@ -169,7 +203,7 @@ def render(report: dict) -> str:
     if stages:
         order = [s for s in STAGE_ORDER if s in stages]
         order += sorted(s for s in stages if s not in STAGE_ORDER)
-        widths = (10, 8, 10, 10, 10, 10)
+        widths = (22, 8, 10, 10, 10, 10)
         lines.append("")
         lines.append("stage durations (traced spans)")
         lines.append(_fmt_row(
@@ -184,9 +218,10 @@ def render(report: dict) -> str:
 
     gaps = report["gaps"]
     if gaps:
-        widths = (18, 8, 12, 12, 12)
+        widths = (40, 8, 12, 12, 12)
         lines.append("")
-        lines.append("inter-stage gaps (same trace id, wall time)")
+        lines.append("inter-stage gaps (same trace id, one stage's end "
+                     "to the next one's start)")
         lines.append(_fmt_row(
             ("edge", "count", "p50_us", "p95_us", "p99_us"), widths))
         for edge, row in gaps.items():
@@ -199,6 +234,12 @@ def render(report: dict) -> str:
         lines.append("queue depths / levels (last snapshot)")
         for name in sorted(report["gauges"]):
             lines.append(f"  {name} = {report['gauges'][name]:g}")
+
+    if report.get("waits"):
+        lines.append("")
+        lines.append("wait shares (time blocked over the snapshots' span)")
+        for name in sorted(report["waits"]):
+            lines.append(f"  {name} = {100.0 * report['waits'][name]:.1f}%")
 
     if report["stalls"]:
         lines.append("")

@@ -6,7 +6,8 @@ One :class:`JsonlSink` owns a directory and two append-only files:
   line ``{"ts": ..., "counters": {...}, "gauges": {...},
   "histograms": {name: {count, sum, mean, p50, p95, p99}}}``.
 * ``spans.jsonl`` — every drained trace span, one JSON object per line
-  (``stage``, ``trace_id``, ``dur_us``, ``ts``, extra fields).
+  (``stage``, ``trace_id``, ``parent``, ``start_ns``, ``end_ns``, extra
+  fields; the clock is described in :mod:`repro.obs.trace`).
 
 The flush thread is a daemon on a short interval; ``stop()`` performs a
 final flush so short runs (tests, bench smokes) never lose the tail.

@@ -1,19 +1,43 @@
 """Pipeline tracing: follow one transition block across the planes.
 
 A traced block picks up a compact 64-bit id at the actor, and every
-stage that touches it — gateway decode/route, shard add, sample refill,
-learner step, priority write-back — records a *span* (stage name, id,
-duration, wall time, a few fields) into a bounded in-process buffer.
-Between processes the id rides a dedicated header field in the v3 wire
-frame (:mod:`repro.net.wire`), so a block that crosses the gateway keeps
-its identity without payload changes; ``trace_id == 0`` means untraced
-and costs one integer compare on the hot path.
+stage that touches it — actor rollout and add, gateway decode/route,
+shard add, sample, learner step, priority write-back — records a *span*
+into a bounded in-process buffer:
+``{stage, trace_id, parent, start_ns, end_ns, **fields}``. Between
+processes the id rides a dedicated header field in the v3 wire frame
+(:mod:`repro.net.wire`), so a block that crosses the gateway keeps its
+identity without payload changes; ``trace_id == 0`` means untraced and
+costs one integer compare on the hot path.
 
 Sampling is deterministic, not random: the id source keeps a sequence
 counter and traces every ``round(1/rate)``-th call. Determinism matters
 here — tests can set rate 1.0 and assert exact propagation, and two runs
 at the same rate trace the same block positions, making run-to-run span
 diffs meaningful.
+
+Two ways to record:
+
+* ``with tracer.span(name, trace_id, **fields):`` around the work. The
+  span always enters ``jax.profiler.TraceAnnotation(name)``: a TraceMe
+  that costs next to nothing while no profiler runs and that puts the
+  span, under its name, on the host plane of a ``jax.profiler`` trace,
+  so an idle stretch of the device is named by the runtime stage that
+  overlaps it. The span is buffered only when its id is nonzero (at
+  sample rate 0, never). An id of 0 inherits the enclosing span's id,
+  and ``parent`` is the enclosing span's name on the same thread. The id
+  may be set inside the block (``sp.trace_id = ...``), for a stage that
+  learns its id from the work it wraps.
+* ``tracer.record(stage, trace_id, dur_us)`` after the fact, for a
+  duration measured elsewhere; it ends at the call.
+
+Clock: ``start_ns`` and ``end_ns`` are ``time.time_ns()``, the wall
+clock (``CLOCK_REALTIME``) that the profiler's host events read
+(TraceMe's ``GetCurrentTimeNanos``). ``jax.profiler.ProfileData`` gives
+a host event's ``start_ns`` relative to the ``profile_start_time`` stat
+of the trace's ``Task Environment`` plane, so a span's ``start_ns`` is
+``profile_start_time + event.start_ns``, up to the few microseconds
+between the two clock reads.
 
 Span semantics per plane:
 
@@ -36,6 +60,8 @@ import os
 import threading
 import time
 from collections import deque
+
+from jax.profiler import TraceAnnotation
 
 # Bounded span buffer: at the default 1s sink flush interval even a
 # rate-1.0 smoke run produces a few thousand spans/s; 64k absorbs sink
@@ -85,14 +111,24 @@ class Tracer:
             return 0
         return self.new_id()
 
+    def span(self, name: str, trace_id: int = 0, **fields) -> "Span":
+        """Context manager around one stage's work (see module docstring)."""
+        return Span(self, name, trace_id, fields)
+
     def record(self, stage: str, trace_id: int, dur_us: float,
                **fields) -> None:
-        """Append one span. No-op for trace_id 0 so call sites can pass
-        the id through unconditionally."""
+        """Append one span of ``dur_us`` ending now. No-op for trace_id 0
+        so call sites can pass the id through unconditionally."""
         if not trace_id:
             return
-        span = {"stage": stage, "trace_id": trace_id,
-                "dur_us": float(dur_us), "ts": time.time()}
+        end = time.time_ns()
+        self._append(stage, trace_id, _enclosing_name(),
+                     end - int(dur_us * 1e3), end, fields)
+
+    def _append(self, stage, trace_id, parent, start_ns, end_ns,
+                fields) -> None:
+        span = {"stage": stage, "trace_id": trace_id, "parent": parent,
+                "start_ns": start_ns, "end_ns": end_ns}
         if fields:
             span.update(fields)
         self._spans.append(span)  # deque.append is atomic under the GIL
@@ -109,3 +145,55 @@ class Tracer:
     def peek(self) -> list[dict]:
         """Non-destructive copy of the buffer (test assertions)."""
         return list(self._spans)
+
+
+# Per-thread stack of open spans: a span's parent is the enclosing one on
+# its own thread.
+_open = threading.local()
+
+
+def _stack() -> list:
+    try:
+        return _open.stack
+    except AttributeError:
+        _open.stack = []
+        return _open.stack
+
+
+def _enclosing_name() -> str | None:
+    stack = _stack()
+    return stack[-1].name if stack else None
+
+
+class Span:
+    """One open span; see :meth:`Tracer.span`."""
+
+    __slots__ = ("_tracer", "name", "trace_id", "_fields", "_ann",
+                 "_parent", "_start")
+
+    def __init__(self, tracer: Tracer, name: str, trace_id: int,
+                 fields: dict):
+        self._tracer = tracer
+        self.name = name
+        self.trace_id = trace_id
+        self._fields = fields
+
+    def __enter__(self) -> "Span":
+        stack = _stack()
+        parent = stack[-1] if stack else None
+        if parent is not None and not self.trace_id:
+            self.trace_id = parent.trace_id
+        self._parent = parent.name if parent is not None else None
+        stack.append(self)
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self._start = time.time_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        end = time.time_ns()
+        self._ann.__exit__(*exc)
+        _stack().pop()
+        if self.trace_id:
+            self._tracer._append(self.name, self.trace_id, self._parent,
+                                 self._start, end, self._fields)

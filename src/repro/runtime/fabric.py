@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import replay as replay_lib, sampling
+from repro.obs import Telemetry
 from repro.runtime import phases
 from repro.runtime.service import (ReplayShard, ServiceStats, ShardFns,
                                    make_shard_fns)
@@ -88,13 +89,13 @@ def _partition_fn(num_shards: int, shard_capacity: int):
     sync per write-back instead of materializing the whole index batch with
     ``np.asarray`` every learner step."""
     @jax.jit
-    def part(indices, priorities):
+    def replay_route_writeback(indices, priorities):
         sids = indices // shard_capacity
         order = jnp.argsort(sids, stable=True)
         counts = jnp.sum(sids[:, None] == jnp.arange(num_shards)[None, :],
                          axis=0)
         return (indices - sids * shard_capacity)[order], priorities[order], counts
-    return part
+    return replay_route_writeback
 
 
 @functools.lru_cache(maxsize=None)
@@ -103,7 +104,7 @@ def _merge_fn(beta: float, shard_capacity: int):
     cached so same-geometry fabric instances share one compilation (the
     shard count specializes via the traced tuple length)."""
     @jax.jit
-    def merge(subs):
+    def replay_merge(subs):
         leaf = jnp.stack([b.leaf_mass for b in subs])
         totals = jnp.stack([b.total_mass for b in subs])
         sizes = jnp.stack([b.size for b in subs])
@@ -113,7 +114,7 @@ def _merge_fn(beta: float, shard_capacity: int):
         items = jax.tree.map(lambda *xs: jnp.concatenate(xs),
                              *[b.items for b in subs])
         return idx, items, w
-    return merge
+    return replay_merge
 
 
 class ReplayFabric:
@@ -156,6 +157,7 @@ class ReplayFabric:
                         ingest_staging=ingest_staging, telemetry=telemetry)
             for k in range(num_shards)]
         self._poll_s = poll_s
+        self._tracer = (telemetry or Telemetry.local()).tracer
         self._ticket = 0
         self._ticket_lock = threading.Lock()
         self._pending: list[replay_lib.SampleBatch | None] = (
@@ -262,7 +264,8 @@ class ReplayFabric:
         self._pending = [None] * self.num_shards
         if self.num_shards == 1:
             return subs[0]  # plain SampleBatch: key == slot, native weights
-        return FabricBatch(*self._merge(subs))
+        with self._tracer.span("fabric/merge"):
+            return FabricBatch(*self._merge(subs))
 
     def write_back(self, indices: jax.Array, priorities: jax.Array,
                    trace_id: int = 0) -> None:
@@ -287,9 +290,12 @@ class ReplayFabric:
             self.shards[0].write_back(indices, priorities,
                                       trace_id=trace_id)
             return
-        slots, prios, counts = self._part(indices, priorities)
+        # the counts' transfer waits for the partition on the device
+        with self._tracer.span("fabric/route_writeback"):
+            slots, prios, counts = self._part(indices, priorities)
+            counts = np.asarray(counts).tolist()
         off = 0
-        for k, n in enumerate(np.asarray(counts).tolist()):
+        for k, n in enumerate(counts):
             if n:
                 self.shards[k].write_back(slots[off:off + n],
                                           prios[off:off + n],

@@ -113,11 +113,11 @@ class InferenceServer:
         self._coalesce_s = coalesce_s
         self._snap = store.get()
 
-        def batched(params, slices, sids):
+        def actor_rollout_batched(params, slices, sids):
             return jax.vmap(lambda sl, sid: phases.act_phase(
                 cfg, env, agent, params, sl, sid))(slices, sids)
 
-        self._fn = jax.jit(batched)
+        self._fn = jax.jit(actor_rollout_batched)
 
         self._pending: collections.deque[_Request] = collections.deque()
         self._cond = threading.Condition()

@@ -4,7 +4,7 @@ runtime.
 The paper decouples acting from learning (§3): actors generate experience at
 their own pace, the learner consumes prioritized samples at its own pace, and
 the replay memory sits between them. To make that decoupling real in code,
-the Ape-X iteration is split here into four pure, independently jittable
+the Ape-X iteration is split here into five pure, independently jittable
 functions:
 
 * ``act_phase``        — roll out T env steps per lane and emit a
@@ -12,12 +12,19 @@ functions:
                          priorities). Touches no replay state.
 * ``replay_add``       — insert a block into a replay shard (FIFO or
                          alloc-into-free-slots, per config).
+* ``replay_sample``    — draw a prioritized batch from a replay shard.
 * ``learn_phase``      — one prioritized update from an already-sampled
                          batch: loss/grads, optimizer step, periodic target
                          sync. Returns fresh priorities; touches no replay
                          state.
 * ``priority_writeback`` — write learner priorities back into the replay
                          shard and run the periodic eviction policy.
+
+Each phase runs under a ``jax.named_scope`` of its own (``act``,
+``replay_add``, ``replay_sample``, ``learn``, ``replay_writeback``), so
+every device op of a program that fuses several phases carries its phase
+in its op metadata, and a profiler trace can split such a program's
+device time by phase.
 
 ``repro.core.apex`` composes them bulk-synchronously inside one jitted step;
 ``repro.runtime.runner`` composes them across actor / replay-service /
@@ -114,6 +121,7 @@ def initial_actor_slice(cfg, env, seed: int, actor_id: int) -> ActorSlice:
 # Act phase
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("act")
 def act_phase(cfg, env, agent, actor_params: Any, aslice: ActorSlice,
               shard_id: jax.Array | int = 0,
               ) -> tuple[ActorSlice, TransitionBlock, dict]:
@@ -197,6 +205,7 @@ def learner_batch_example(cfg, item: Any) -> tuple[Any, jax.Array]:
     return items, jnp.ones((cfg.batch_size,), jnp.float32)
 
 
+@jax.named_scope("replay_add")
 def replay_add(cfg, replay_state: replay_lib.ReplayState,
                block: TransitionBlock) -> replay_lib.ReplayState:
     """Insert a transition block into a replay shard (the replay side of
@@ -206,10 +215,19 @@ def replay_add(cfg, replay_state: replay_lib.ReplayState,
     return add(cfg.replay, replay_state, block.items, block.priorities)
 
 
+@jax.named_scope("replay_sample")
+def replay_sample(cfg, replay_state: replay_lib.ReplayState, rng: jax.Array,
+                  batch_size: int) -> replay_lib.SampleBatch:
+    """A prioritized batch of ``batch_size`` from a replay shard (Alg. 2
+    l.4)."""
+    return replay_lib.sample(cfg.replay, replay_state, rng, batch_size)
+
+
 # ---------------------------------------------------------------------------
 # Learn phase
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("learn")
 def learn_phase(cfg, agent, optimizer, lslice: LearnerSlice, items: Any,
                 weights: jax.Array, axis_name: str | None = None,
                 ) -> tuple[LearnerSlice, jax.Array, dict]:
@@ -231,6 +249,7 @@ def learn_phase(cfg, agent, optimizer, lslice: LearnerSlice, items: Any,
     return lslice, new_prios, metrics
 
 
+@jax.named_scope("replay_writeback")
 def priority_writeback(cfg, replay_state: replay_lib.ReplayState,
                        indices: jax.Array, priorities: jax.Array,
                        learner_step: jax.Array, rng: jax.Array,

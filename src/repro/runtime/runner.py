@@ -221,7 +221,10 @@ class RuntimeHandles:
     ``on_handles`` callback once every plane has started. This is the
     surface the fault-injection harness (``repro.testing.chaos``) reaches
     through to kill processes, sever transports, and freeze shard owners —
-    deliberately raw, not a stable public API."""
+    deliberately raw, not a stable public API. ``telemetry`` is the run's
+    one :class:`~repro.obs.Telemetry`: its registry holds every plane's
+    counters and histograms by name (``actor/rollouts``,
+    ``learner/batch_wait_us``, ``shard0/sync_wait_us``, ...), live."""
 
     stop: threading.Event            # the run's stop event
     fabric: Any                      # ReplayFabric | None (learner_remote)
@@ -233,7 +236,7 @@ class RuntimeHandles:
     procs_lock: Any                  # guards ``procs`` slot swaps
     snapshots: Any                   # SnapshotService | None
     learner_box: dict                # {"steps", "lslice", "live"}
-    counters: dict                   # the run's shared counters dict
+    telemetry: Any                   # the run's Telemetry (registry, tracer)
 
 
 def _actor_geometry(cfg, acfg: AsyncConfig):
@@ -245,6 +248,32 @@ def _actor_geometry(cfg, acfg: AsyncConfig):
     host)."""
     return dataclasses.replace(
         cfg, num_shards=max(acfg.actor_threads + acfg.actor_procs, 1))
+
+
+def make_runner_fns(cfg, env, agent, optimizer):
+    """The runner's three programs — the actor's rollout, one learner step,
+    and ``k`` learner steps in one call — each a named function, so its
+    device ops carry a stable module name (``jit_actor_rollout``,
+    ``jit_learner_step``, ``jit_learner_step_many``) in a profile."""
+
+    def actor_rollout(params, aslice, shard_id):
+        return phases.act_phase(cfg, env, agent, params, aslice, shard_id)
+
+    def learner_step(lslice, items, weights):
+        return phases.learn_phase(cfg, agent, optimizer, lslice, items,
+                                  weights, None)
+
+    def learner_step_many(lslice, items_k, weights_k):
+        # One jitted call consumes k double-buffered batches via lax.scan,
+        # amortizing dispatch overhead when per-batch compute is small.
+        def body(lsl, xw):
+            lsl, prios, _ = phases.learn_phase(cfg, agent, optimizer, lsl,
+                                               xw[0], xw[1], None)
+            return lsl, prios
+        return jax.lax.scan(body, lslice, (items_k, weights_k))
+
+    return (jax.jit(actor_rollout), jax.jit(learner_step),
+            jax.jit(learner_step_many))
 
 
 def run_async(cfg, acfg: AsyncConfig, env, agent, optimizer,
@@ -471,24 +500,11 @@ def run_async(cfg, acfg: AsyncConfig, env, agent, optimizer,
             source = StagedSource(source, poll_s=acfg.starve_timeout_s,
                                   telemetry=tel)
 
-    act_fn = (jax.jit(lambda p, sl, sid: phases.act_phase(
-                  cfg, env, agent, p, sl, sid))
-              if server is None and acfg.actor_threads > 0 else None)
+    rollout_fn, learn_fn, learn_many_fn = make_runner_fns(cfg, env, agent,
+                                                          optimizer)
+    act_fn = (rollout_fn if server is None and acfg.actor_threads > 0
+              else None)
     learn_k = acfg.learn_batches_per_step
-    if not serving:
-        learn_fn = jax.jit(lambda lsl, items, w: phases.learn_phase(
-            cfg, agent, optimizer, lsl, items, w, None))
-        if learn_k > 1:
-            # Satellite of the prefetch queues: one jitted call consumes k
-            # double-buffered batches via lax.scan, amortizing dispatch
-            # overhead when per-batch compute is small.
-            def _learn_scan(lsl, items_k, w_k):
-                def body(l, xw):
-                    l, prios, _ = phases.learn_phase(cfg, agent, optimizer,
-                                                     l, xw[0], xw[1], None)
-                    return l, prios
-                return jax.lax.scan(body, lsl, (items_k, w_k))
-            learn_many_fn = jax.jit(_learn_scan)
 
     # Warm the caches before the clock starts: one throwaway rollout (the
     # batched server wave when inference batching is on, the per-actor fn
@@ -526,10 +542,15 @@ def run_async(cfg, acfg: AsyncConfig, env, agent, optimizer,
                 lslice, items_k_ex,
                 jnp.ones((learn_k, cfg.batch_size), jnp.float32)))
     stop = threading.Event()
-    counters = {"actor_transitions": 0, "actor_blocked": 0,
-                "learner_starved": 0, "rollouts": 0, "actor_restarts": 0,
-                "actor_proc_exits": 0}
-    counter_lock = threading.Lock()
+    # The run's counters live in the registry, so they move while the run
+    # does (RuntimeHandles.telemetry) and the sink exports them.
+    c_rollouts = tel.counter("actor/rollouts")
+    c_transitions = tel.counter("actor/transitions")
+    c_add_blocked = tel.counter("actor/add_blocked")
+    c_steps = tel.counter("learner/steps")
+    c_batch_wait = tel.counter("learner/batch_wait_us")
+    c_starved = tel.counter("learner/starved")
+    tracer = tel.tracer
     last_metrics: list[Any] = [None]
     thread_errors: list[BaseException] = []
 
@@ -549,39 +570,37 @@ def run_async(cfg, acfg: AsyncConfig, env, agent, optimizer,
         sl = slices[t]
         sid = jnp.int32(t)
         snap = store.get()
-        rollouts = blocked = pushed = 0
-        tracer = tel.tracer
+        rollouts = 0
         while not stop.is_set():
             # A traced rollout opens the block's pipeline trace: the same id
             # rides the fabric add (and, for remote actors, the wire header)
             # so the report can line stages up per block.
             tid = tracer.sample()
-            t_roll = time.perf_counter() if tid else 0.0
-            if server is not None:
-                # Batched inference: param refresh happens server-side.
-                res = server.act(sl, t)
-                if res is None:  # server (or runtime) stopping
-                    break
-                sl, block, metrics = res
-            else:
-                if rollouts % cfg.param_sync_period == 0:
+            if server is None and rollouts % cfg.param_sync_period == 0:
+                with tracer.span("actor/param_refresh", tid, actor=t):
                     snap = store.get()
-                sl, block, metrics = act_fn(snap.params, sl, sid)
-            if tid:
-                jax.block_until_ready(block)  # honest rollout duration
-                tracer.record("actor", tid,
-                              1e6 * (time.perf_counter() - t_roll), actor=t)
-            while not stop.is_set():
-                if fabric.add(block, timeout=acfg.add_poll_s, trace_id=tid):
-                    pushed += 1
-                    break
-                blocked += 1  # bounded queue full: actor is backpressured
+            with tracer.span("actor/rollout", tid, actor=t):
+                if server is not None:
+                    # Batched inference: param refresh happens server-side.
+                    res = server.act(sl, t)
+                    if res is None:  # server (or runtime) stopping
+                        break
+                    sl, block, metrics = res
+                else:
+                    sl, block, metrics = act_fn(snap.params, sl, sid)
+                if tid:
+                    jax.block_until_ready(block)  # honest rollout duration
+            with tracer.span("actor/add", tid, actor=t):
+                while not stop.is_set():
+                    if fabric.add(block, timeout=acfg.add_poll_s,
+                                  trace_id=tid):
+                        c_transitions.inc(block_transitions)
+                        break
+                    # bounded queue full: actor is backpressured
+                    c_add_blocked.inc()
             rollouts += 1
+            c_rollouts.inc()
             last_metrics[0] = metrics
-        with counter_lock:
-            counters["actor_transitions"] += pushed * block_transitions
-            counters["actor_blocked"] += blocked
-            counters["rollouts"] += rollouts
 
     # -- learner thread ---------------------------------------------------
     # "live" is the snapshot service's view: one atomic (steps, lslice)
@@ -593,30 +612,34 @@ def run_async(cfg, acfg: AsyncConfig, env, agent, optimizer,
     def learner_loop() -> None:
         lsl = learner_box["lslice"]
         steps = resume_steps
-        starved = 0
         pending: list = []  # gathered batches for one k-sized jitted call
         while steps < acfg.total_learner_steps and not stop.is_set():
-            batch = source.get_batch(timeout=acfg.starve_timeout_s)
-            if batch is None:
-                starved += 1  # replay below min-fill or prefetch lagging
-                continue
-            if learn_k == 1:
+            t_wait = time.perf_counter()
+            with tracer.span("learner/get_batch") as sp:
+                batch = source.get_batch(timeout=acfg.starve_timeout_s)
                 # The source stamped this batch's consume-plane trace id
                 # when it drew it; the learn span and the priority
                 # write-back inherit it (k > 1 chunks stay untraced — one
                 # jitted call spans k batches, so a per-batch duration
                 # would be a lie).
-                tid = source.last_trace_id
-                t_learn = time.perf_counter() if tid else 0.0
-                lsl, new_prios, _ = learn_fn(lsl, batch.items,
-                                             batch.is_weights)
-                if tid:
-                    jax.block_until_ready(new_prios)  # honest learn duration
-                    tel.tracer.record(
-                        "learn", tid,
-                        1e6 * (time.perf_counter() - t_learn), step=steps)
-                source.write_back(batch.indices, new_prios, trace_id=tid)
+                tid = (source.last_trace_id
+                       if batch is not None and learn_k == 1 else 0)
+                sp.trace_id = tid
+            c_batch_wait.inc(int(1e6 * (time.perf_counter() - t_wait)))
+            if batch is None:
+                c_starved.inc()  # replay below min-fill or prefetch lagging
+                continue
+            if learn_k == 1:
+                with tracer.span("learner/step", tid, step=steps):
+                    lsl, new_prios, _ = learn_fn(lsl, batch.items,
+                                                 batch.is_weights)
+                    if tid:
+                        # honest learn duration
+                        jax.block_until_ready(new_prios)
+                with tracer.span("learner/write_back", tid):
+                    source.write_back(batch.indices, new_prios, trace_id=tid)
                 steps += 1
+                c_steps.inc()
             else:
                 pending.append(batch)
                 if len(pending) < learn_k:
@@ -624,25 +647,28 @@ def run_async(cfg, acfg: AsyncConfig, env, agent, optimizer,
                 items_k = jax.tree.map(lambda *xs: jnp.stack(xs),
                                        *[b.items for b in pending])
                 w_k = jnp.stack([b.is_weights for b in pending])
-                lsl, prios_k = learn_many_fn(lsl, items_k, w_k)
+                with tracer.span("learner/step"):
+                    lsl, prios_k = learn_many_fn(lsl, items_k, w_k)
                 # One write-back per consumed batch: each application ticks
                 # the shard's eviction clock once, so k-batching leaves the
                 # paper's evict-every-N-steps pacing unchanged.
-                for i, b in enumerate(pending):
-                    source.write_back(b.indices, prios_k[i])
+                with tracer.span("learner/write_back"):
+                    for i, b in enumerate(pending):
+                        source.write_back(b.indices, prios_k[i])
                 pending = []
                 steps += learn_k
+                c_steps.inc(learn_k)
             learner_box["live"] = (steps, lsl)
             if steps % acfg.publish_every < learn_k:
-                version = store.publish(lsl.params)
-                # Remote transports also ship the snapshot upstream, so the
-                # actors feeding the (remote) fabric keep pulling
-                # learning-current params; local sources no-op.
-                source.publish_params(version, lsl.params)
+                with tracer.span("learner/publish", tid):
+                    version = store.publish(lsl.params)
+                    # Remote transports also ship the snapshot upstream, so
+                    # the actors feeding the (remote) fabric keep pulling
+                    # learning-current params; local sources no-op.
+                    source.publish_params(version, lsl.params)
         jax.block_until_ready(lsl.params)
         learner_box["lslice"] = lsl
         learner_box["steps"] = steps
-        counters["learner_starved"] = starved
 
     def serve_loop() -> None:
         """Serve-sampling mode: no local learner. The learner clock is the
@@ -699,8 +725,6 @@ def run_async(cfg, acfg: AsyncConfig, env, agent, optimizer,
                     continue
                 if retry_at[j] == 0.0:
                     # First observation of this death.
-                    with counter_lock:
-                        counters["actor_proc_exits"] += 1
                     c_proc_exits.inc()
                     if (not acfg.supervise_actors
                             or restarts[j] >= acfg.actor_restart_limit):
@@ -724,8 +748,6 @@ def run_async(cfg, acfg: AsyncConfig, env, agent, optimizer,
                 retry_at[j] = 0.0
                 with procs_lock:
                     procs[j] = spawn_actor(j)
-                with counter_lock:
-                    counters["actor_restarts"] += 1
                 c_restarts.inc()
                 obslog.emit("actor-restart", slot=j, attempt=restarts[j])
             if acfg.actor_threads == 0 and n and all(dead):
@@ -836,8 +858,7 @@ def run_async(cfg, acfg: AsyncConfig, env, agent, optimizer,
         on_handles(RuntimeHandles(
             stop=stop, fabric=fabric, gateway=gateway, source=source,
             store=store, procs=procs, procs_lock=procs_lock,
-            snapshots=snapshots, learner_box=learner_box,
-            counters=counters))
+            snapshots=snapshots, learner_box=learner_box, telemetry=tel))
     learner.join(timeout=acfg.max_seconds)
     stop.set()
     if server is not None:
@@ -889,16 +910,14 @@ def run_async(cfg, acfg: AsyncConfig, env, agent, optimizer,
         if gateway.error is not None:
             thread_errors.append(gateway.error)
         gw_snap = gateway.snapshot()
-        with counter_lock:
-            # Includes blocks that landed during the shutdown drain grace:
-            # they were generated inside the measured window and were
-            # sitting in the bounded in-flight window — the remote analogue
-            # of in-process blocks parked in shard add queues at stop,
-            # which the thread counters include the same way.
-            counters["actor_transitions"] += gw_snap.transitions_in
-            counters["actor_blocked"] += (gw_snap.add_retries
-                                          + gw_snap.client_blocked)
-            counters["rollouts"] += gw_snap.blocks_in
+        # Includes blocks that landed during the shutdown drain grace: they
+        # were generated inside the measured window and were sitting in the
+        # bounded in-flight window — the remote analogue of in-process
+        # blocks parked in shard add queues at stop, which the thread
+        # counters include the same way.
+        c_transitions.inc(gw_snap.transitions_in)
+        c_add_blocked.inc(gw_snap.add_retries + gw_snap.client_blocked)
+        c_rollouts.inc(gw_snap.blocks_in)
     if source is not None:
         # Stop the sample plane before the fabric: a staged source's stager
         # thread is still pulling prefetched batches, and the remote client
@@ -933,20 +952,20 @@ def run_async(cfg, acfg: AsyncConfig, env, agent, optimizer,
     agg = fabric.snapshot() if fabric is not None else source.snapshot()
     stats = {
         "seconds": dt,
-        "actor_transitions": float(counters["actor_transitions"]),
+        "actor_transitions": float(c_transitions.value),
         "learner_transitions": float(steps * cfg.batch_size),
-        "actor_tps": counters["actor_transitions"] / dt if dt > 0 else 0.0,
+        "actor_tps": c_transitions.value / dt if dt > 0 else 0.0,
         "learner_tps": steps * cfg.batch_size / dt if dt > 0 else 0.0,
-        "rollouts": float(counters["rollouts"]),
+        "rollouts": float(c_rollouts.value),
         "learner_steps": float(steps),
-        "actor_blocked": float(counters["actor_blocked"]),
-        "learner_starved": float(counters["learner_starved"]),
+        "actor_blocked": float(c_add_blocked.value),
+        "learner_starved": float(c_starved.value),
         "param_version": float(store.version),
         "replay_size": float(agg.replay_size),
         "replay_shards": float(acfg.replay_shards),
         "actor_procs": float(acfg.actor_procs),
-        "actor_restarts": float(counters["actor_restarts"]),
-        "actor_proc_exits": float(counters["actor_proc_exits"]),
+        "actor_restarts": float(c_restarts.value),
+        "actor_proc_exits": float(c_proc_exits.value),
         "resumed_from_step": float(resume_steps),
     }
     if snapshots is not None:

@@ -57,6 +57,10 @@ _SIZE_REFRESH_OPS = 32
 # synced — a traced span must be an honest duration.
 _LATENCY_SAMPLE_EVERY = 8
 
+# The owner thread's span per op kind (``repro.obs.trace``).
+_SPAN = {"add": "shard/add", "sample": "shard/sample",
+         "writeback": "shard/writeback"}
+
 
 @dataclasses.dataclass
 class ServiceStats:
@@ -135,18 +139,31 @@ class ShardFns(NamedTuple):
 
 
 def make_shard_fns(cfg, batch_size: int) -> ShardFns:
+    """The shard's five programs, each a named function so its device ops
+    carry a stable module name (``jit_replay_add``, ...) in a profile."""
     rcfg = cfg.replay
+
+    def replay_add(st, block):
+        return phases.replay_add(cfg, st, block)
+
+    def replay_sample(st, rng):
+        return phases.replay_sample(cfg, st, rng, batch_size)
+
+    def replay_writeback(st, idx, prios, step, rng):
+        return phases.priority_writeback(cfg, st, idx, prios, step, rng)
+
+    def replay_can_sample(st):
+        return replay_lib.can_sample(rcfg, st)
+
+    def replay_rng_split(k):
+        return jax.random.split(k)
+
     return ShardFns(
-        add=jax.jit(lambda st, block: phases.replay_add(cfg, st, block),
-                    donate_argnums=(0,)),
-        sample=jax.jit(
-            lambda st, rng: replay_lib.sample(rcfg, st, rng, batch_size)),
-        writeback=jax.jit(
-            lambda st, idx, prios, step, rng: phases.priority_writeback(
-                cfg, st, idx, prios, step, rng),
-            donate_argnums=(0,)),
-        can_sample=jax.jit(lambda st: replay_lib.can_sample(rcfg, st)),
-        split=jax.jit(lambda k: jax.random.split(k)),
+        add=jax.jit(replay_add, donate_argnums=(0,)),
+        sample=jax.jit(replay_sample),
+        writeback=jax.jit(replay_writeback, donate_argnums=(0,)),
+        can_sample=jax.jit(replay_can_sample),
+        split=jax.jit(replay_rng_split),
     )
 
 
@@ -211,6 +228,9 @@ class ReplayShard:
         self._g_size = self._tel.gauge(f"{pre}/replay_size")
         self._c_add_blocked = self._tel.counter(f"{pre}/add_backpressure")
         self._c_starved = self._tel.counter(f"{pre}/get_batch_starved")
+        # microseconds the owner thread sat blocked on device results
+        self._c_sync_wait = self._tel.counter(f"{pre}/sync_wait_us")
+        self._tracer = self._tel.tracer
 
     @property
     def learner_steps(self) -> int:
@@ -387,28 +407,35 @@ class ReplayShard:
         if refresh:
             # Outside the lock: int() blocks on the device, and readers only
             # need the counters to stay consistent, not the size to be fresh.
-            size = int(self._state.size)
+            size = int(self._sync(self._state.size))
             with self._stats_lock:
                 self.stats.replay_size = size
             self._g_size.set(size)
 
     def _timed(self, kind: str, fn, *args, trace_id: int = 0):
-        """Dispatch an op; every ``_LATENCY_SAMPLE_EVERY``th call of each
-        kind — and every traced call — is synced and timed into the
-        shard's ``<kind>_us`` histogram (hot-path regressions surface in
-        runner progress logs, bench counters, and the obs report). Traced
-        calls additionally record a pipeline span under the op's stage
-        name so the block/batch chain stays linked across planes."""
+        """Dispatch an op inside its ``shard/<kind>`` span; every
+        ``_LATENCY_SAMPLE_EVERY``th call of each kind — and every traced
+        call — is synced and timed into the shard's ``<kind>_us``
+        histogram (hot-path regressions surface in runner progress logs,
+        bench counters, and the obs report). A traced call's span is
+        buffered under the block's or batch's id, so the chain stays
+        linked across planes."""
         self._op_seq[kind] += 1
-        if self._op_seq[kind] % _LATENCY_SAMPLE_EVERY and not trace_id:
-            return fn(*args)
+        with self._tracer.span(_SPAN[kind], trace_id, shard=self.shard_id):
+            if self._op_seq[kind] % _LATENCY_SAMPLE_EVERY and not trace_id:
+                return fn(*args)
+            t0 = time.perf_counter()
+            out = self._sync(fn(*args))
+            self._hists[kind].record(1e6 * (time.perf_counter() - t0))
+            return out
+
+    def _sync(self, out):
+        """Wait for a device result: every host wait of the owner thread
+        goes through here (``shard/sync`` span, ``sync_wait_us``)."""
         t0 = time.perf_counter()
-        out = jax.block_until_ready(fn(*args))
-        us = 1e6 * (time.perf_counter() - t0)
-        self._hists[kind].record(us)
-        if trace_id:
-            self._tel.tracer.record(kind, trace_id, us,
-                                    shard=self.shard_id)
+        with self._tracer.span("shard/sync"):
+            jax.block_until_ready(out)
+        self._c_sync_wait.inc(int(1e6 * (time.perf_counter() - t0)))
         return out
 
     def _stage_block(self, block: phases.TransitionBlock):
@@ -449,7 +476,7 @@ class ReplayShard:
         if not self._ready:
             if self.stats.transitions_added < self._cfg.replay.min_fill:
                 return False
-            self._ready = bool(self._fns.can_sample(self._state))
+            self._ready = bool(self._sync(self._fns.can_sample(self._state)))
         return self._ready
 
     def _next_rng(self) -> jax.Array:
@@ -549,7 +576,8 @@ class ReplayShard:
             if not progressed:
                 # Idle: park on the add queue so actors wake us immediately.
                 try:
-                    block, tid = self._add_q.get(timeout=0.002)
+                    with self._tracer.span("shard/idle"):
+                        block, tid = self._add_q.get(timeout=0.002)
                 except queue.Empty:
                     continue
                 # A lone block has no overlap partner, but staging it still

@@ -294,3 +294,35 @@ def test_run_async_end_to_end():
     # every consumed batch's priorities came back to the replay service
     assert res.service_stats.updates_applied == 8
     assert res.service_stats.transitions_added == s["actor_transitions"]
+
+
+def test_actor_counters_move_while_actors_run():
+    """The actor counters live in the run's registry (RuntimeHandles
+    .telemetry) and move per rollout, while the actor threads still run,
+    not only when a thread exits."""
+    preset = tiny_preset()
+    seen = {}
+
+    def on_handles(h):
+        reg = h.telemetry.registry
+        deadline = time.monotonic() + 60.0
+        while (reg.counter("actor/rollouts").value < 3
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
+        seen["rollouts"] = reg.counter("actor/rollouts").value
+        seen["transitions"] = reg.counter("actor/transitions").value
+        seen["alive"] = sorted(th.name for th in threading.enumerate()
+                               if th.name in ("actor-0", "actor-1")
+                               and th.is_alive())
+        h.stop.set()
+
+    res = run_async(preset.apex, AsyncConfig(actor_threads=2,
+                                             total_learner_steps=10 ** 6,
+                                             max_seconds=120.0, seed=5),
+                    preset.env, preset.agent, preset.make_optimizer(),
+                    on_handles=on_handles)
+    assert seen["rollouts"] >= 3
+    assert seen["alive"] == ["actor-0", "actor-1"]
+    assert seen["transitions"] > 0
+    assert res.stats["rollouts"] >= seen["rollouts"]
+    assert res.stats["actor_transitions"] >= seen["transitions"]

@@ -3,9 +3,13 @@ numpy order statistics), deterministic trace sampling, trace-id
 propagation across a loopback gateway round trip (tcp AND shm), the
 JSONL sink, the structured log emitter, and the run report end to end."""
 
+import glob
 import json
 import math
 import threading
+import time
+
+import jax
 
 import numpy as np
 import pytest
@@ -133,6 +137,48 @@ def test_tracer_record_drops_untraced_and_drains_in_order():
     assert tr.drain() == []               # drained means drained
 
 
+def test_span_lands_in_the_profiler_trace_on_its_clock(tmp_path):
+    """A span is a host event of a ``jax.profiler`` trace under its name;
+    its buffered start is the event's start on the profiler's clock
+    (``profile_start_time + start_ns``), and its parent is the enclosing
+    span on the same thread, whose id it inherits."""
+    tr = Tracer(1.0)
+    tid = tr.new_id()
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.span("test/outer", tid, k=1):
+            with tr.span("test/inner"):
+                time.sleep(0.005)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    start = dict(pd.find_plane_with_name("Task Environment").stats)[
+        "profile_start_time"]
+    events = {ev.name: ev for plane in pd.planes if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events}
+    spans = {s["stage"]: s for s in tr.peek()}
+    for name in ("test/outer", "test/inner"):
+        ev = events[name]
+        assert abs(start + ev.start_ns - spans[name]["start_ns"]) < 1e6
+        assert abs(ev.duration_ns - (spans[name]["end_ns"]
+                                     - spans[name]["start_ns"])) < 1e6
+    assert spans["test/inner"]["parent"] == "test/outer"
+    assert spans["test/outer"]["parent"] is None
+    assert spans["test/inner"]["trace_id"] == tid
+    assert spans["test/outer"]["k"] == 1
+
+
+def test_untraced_spans_buffer_nothing():
+    """At sample rate 0 a span still annotates, but nothing is buffered;
+    an id set inside the block is buffered."""
+    off = Tracer(0.0)
+    with off.span("a", off.sample()):
+        with off.span("b"):
+            pass
+    assert off.peek() == []
+    with off.span("late") as sp:
+        sp.trace_id = 5
+    assert [s["stage"] for s in off.peek()] == ["late"]
+
+
 # --- sink + log --------------------------------------------------------------
 
 def test_jsonl_sink_writes_metrics_and_spans(tmp_path):
@@ -149,7 +195,8 @@ def test_jsonl_sink_writes_metrics_and_spans(tmp_path):
              (tmp_path / SPANS_FILE).read_text().splitlines()]
     assert metrics[-1]["counters"]["c"] == 5
     assert metrics[-1]["ts"] > 0
-    assert spans[0]["stage"] == "actor" and spans[0]["dur_us"] == 11.0
+    assert spans[0]["stage"] == "actor"
+    assert spans[0]["end_ns"] - spans[0]["start_ns"] == 11000
 
 
 def test_log_format_line_is_machine_parseable():
@@ -288,10 +335,12 @@ def test_trace_id_propagates_through_gateway_round_trip(kind):
 # --- end to end: traced run + report (acceptance) ----------------------------
 
 def test_traced_run_report_shows_every_stage(tmp_path):
-    """A tiny traced async run must yield a report where all five local
-    pipeline stages (actor/add/sample/learn/writeback) show nonzero
-    counts, rates, and latency percentiles, plus queue-depth gauges and
-    the derived *_us views still feeding ServiceStats."""
+    """A tiny traced async run must yield a report where every local
+    pipeline stage (actor rollout and add, shard add, sample, learner
+    fetch, step and write-back, shard write-back) shows nonzero counts,
+    rates, and latency percentiles, plus queue-depth gauges, the derived
+    *_us views still feeding ServiceStats, and the queue gaps between
+    stages."""
     preset = tiny_preset()
     acfg = AsyncConfig(actor_threads=2, total_learner_steps=6,
                        max_seconds=60.0, seed=3,
@@ -302,20 +351,52 @@ def test_traced_run_report_shows_every_stage(tmp_path):
     assert res.service_stats.add_us > 0.0  # derived view still populated
 
     rep = report_lib.load_report(str(tmp_path))
-    for stage in ("actor", "add", "sample", "learn", "writeback"):
+    stages = ("actor/rollout", "actor/add", "shard/add", "sample",
+              "learner/get_batch", "learner/step", "learner/write_back",
+              "shard/writeback")
+    for stage in stages:
         row = rep["stages"][stage]
         assert row["count"] > 0, stage
         assert row["rate_hz"] > 0.0, stage
         assert row["p50_us"] > 0.0, stage
+    for edge in ("actor/add->shard/add", "learner/write_back->shard/writeback"):
+        assert rep["gaps"][edge]["count"] > 0, edge
+        assert rep["gaps"][edge]["p50_us"] >= 0.0, edge
     assert "shard0/replay_size" in rep["gauges"]
     assert rep["histograms"]["shard0/add_us"]["count"] > 0
     # the rendered table carries every stage row
     text = report_lib.render(rep)
-    for stage in ("actor", "add", "sample", "learn", "writeback"):
+    for stage in stages:
         assert stage in text
     # the CLI entry point renders the same directory (exit code 0)
     assert report_lib.main([str(tmp_path)]) == 0
     assert report_lib.main([str(tmp_path / "nope")]) == 2
+
+
+def test_report_gives_each_wait_counter_as_a_share_of_its_thread(tmp_path):
+    """``*_wait_us`` counters read as the share of the first-to-last
+    snapshot span their thread sat blocked; other counters do not."""
+    snaps = [{"ts": 100.0, "counters": {"learner/batch_wait_us": 1e6,
+                                        "shard0/sync_wait_us": 0,
+                                        "learner/steps": 10}},
+             {"ts": 104.0, "counters": {"learner/batch_wait_us": 2e6,
+                                        "shard0/sync_wait_us": 3e6,
+                                        "shard1/sync_wait_us": 2e6,
+                                        "learner/steps": 50}}]
+    with open(tmp_path / METRICS_FILE, "w") as f:
+        for snap in snaps:
+            f.write(json.dumps(snap) + "\n")
+    rep = report_lib.load_report(str(tmp_path))
+    assert rep["waits"] == pytest.approx({"learner/batch_wait_us": 0.25,
+                                          "shard0/sync_wait_us": 0.75,
+                                          "shard1/sync_wait_us": 0.5})
+    text = report_lib.render(rep)
+    assert "learner/batch_wait_us = 25.0%" in text
+    assert "shard0/sync_wait_us = 75.0%" in text
+    # one snapshot spans no time: no share
+    with open(tmp_path / METRICS_FILE, "w") as f:
+        f.write(json.dumps(snaps[1]) + "\n")
+    assert report_lib.load_report(str(tmp_path))["waits"] == {}
 
 
 def test_trace_sample_rate_validated_by_async_config():
